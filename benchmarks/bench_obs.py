@@ -26,6 +26,10 @@ All four are deterministic pass/fail counts gated at zero by
 ``check_regression.py`` (no machine-dependent baseline). The merged
 fleet snapshot is written to ``FLEET_snapshot.json`` (uploaded as a CI
 artifact next to the BENCH/METRICS trajectory files).
+
+The workers run on the CPU (``JAX_PLATFORMS=cpu`` in their environment):
+what is measured here is the collector's merge, not the chip, and a chip
+belongs to one process at a time. The parent never imports jax.
 """
 from __future__ import annotations
 
@@ -169,6 +173,7 @@ def run(n_workers: int = N_WORKERS) -> dict:
     env = dict(os.environ)
     env["PYTHONPATH"] = os.pathsep.join(
         [os.path.join(root, "src"), root, env.get("PYTHONPATH", "")])
+    env["JAX_PLATFORMS"] = "cpu"
     try:
         with tempfile.TemporaryDirectory(prefix="obs-spool-") as spool:
             procs = [subprocess.Popen(
@@ -234,6 +239,9 @@ def main(smoke: bool = False) -> None:
 
 if __name__ == "__main__":
     if "--worker" in sys.argv:
+        from repro.utils.compile_cache import enable_compile_cache
+
+        enable_compile_cache()
         args = sys.argv[1:]
         run_worker(args[args.index("--worker") + 1],
                    args[args.index("--spool") + 1],

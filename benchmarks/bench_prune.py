@@ -215,6 +215,9 @@ def main(smoke: bool = False, strict: bool = False) -> None:
 
 
 if __name__ == "__main__":
+    from repro.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     if "--emit-metrics" in sys.argv:
         os.environ["BENCH_EMIT_METRICS"] = "1"
     main(smoke="--smoke" in sys.argv, strict="--strict" in sys.argv)

@@ -60,7 +60,8 @@ def peel_collective_scaling(csv=True):
     """Structural scaling of one distributed peel pass: per-device collective
     payload vs worker count (lowered HLO on fabricated devices; the paper's
     cores-axis replaced by the shard axis). Runs in a subprocess because the
-    device count must be fixed before jax initializes."""
+    device count must be fixed before jax initializes; the subprocess is
+    pinned to the CPU, whose fabricated devices are what it counts."""
     import os
     import subprocess
     import sys
@@ -69,7 +70,7 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=64"
 import jax, jax.numpy as jnp, numpy as np
 from repro.core.distributed import make_peel_pass, shard_edges
-from repro.utils.compat import make_mesh_auto
+from repro.utils.mesh import make_mesh_auto
 from repro.core.pbahmani import init_state
 from repro.graphs.generators import rmat
 from repro.launch.hlo_analysis import collective_stats
@@ -88,6 +89,7 @@ for w in (2, 4, 16, 64):
 '''
     env = dict(os.environ)
     env["PYTHONPATH"] = "src"
+    env["JAX_PLATFORMS"] = "cpu"
     out = subprocess.run([sys.executable, "-c", script], env=env,
                          capture_output=True, text=True, timeout=600)
     if out.returncode != 0:
@@ -98,4 +100,7 @@ for w in (2, 4, 16, 64):
 
 
 if __name__ == "__main__":
+    from repro.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     main()

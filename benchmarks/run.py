@@ -56,4 +56,7 @@ if __name__ == "__main__":
         # every bench's write_bench_json also writes METRICS_<name>.json
         # (obs registry + recompile-audit snapshot) for the CI gate
         os.environ["BENCH_EMIT_METRICS"] = "1"
+    from repro.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     main()

@@ -32,7 +32,7 @@ def main():
         if n_dev % m == 0:
             model = m
             break
-    from repro.utils.compat import make_mesh_auto
+    from repro.utils.mesh import make_mesh_auto
     mesh = make_mesh_auto((n_dev // model, model), ("data", "model"))
     print(f"mesh: {dict(mesh.shape)} over {n_dev} device(s)")
 
@@ -68,4 +68,7 @@ def main():
 
 
 if __name__ == "__main__":
+    from repro.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     main()

@@ -8,7 +8,7 @@ families reuse:
   * :func:`find_jit_contexts` — every function the tracer will run:
     ``@jax.jit`` / ``@partial(jax.jit, static_argnames=...)`` decorated
     defs, ``name = jax.jit(fn_or_lambda, ...)`` wrappings, and bodies
-    handed to ``shard_map`` / ``shard_map_compat``. Each context knows
+    handed to ``shard_map``. Each context knows
     its traced parameter names (params minus ``static_argnames``).
   * :func:`find_shard_map_calls` — shard_map call sites with their
     resolved body function and the axis tokens used in ``P(...)`` specs
@@ -297,8 +297,7 @@ def find_jit_contexts(mod: ModuleInfo) -> list[JitContext]:
 # ---------------------------------------------------------------------------
 # shard_map call sites (shared by context discovery and the RPR4xx rules)
 # ---------------------------------------------------------------------------
-SHARD_MAP_NAMES = ("shard_map", "shard_map_compat", "jax.shard_map",
-                   "shmap", "jax.experimental.shard_map.shard_map")
+SHARD_MAP_NAMES = ("shard_map", "jax.shard_map", "shmap")
 
 
 @dataclass

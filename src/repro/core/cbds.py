@@ -32,6 +32,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core.density import ratio
 from repro.core.kcore import _kcore_jit, kcore_np
 from repro.graphs.graph import Graph
 
@@ -106,7 +107,7 @@ def _cbds_jit(
         member, m_v, m_e, n_added = _augment_once(member, m_v, m_e, src, dst, n_nodes)
         n_legit_total = n_legit_total + n_added
 
-    density = m_e.astype(jnp.float32) / jnp.maximum(m_v, 1).astype(jnp.float32)
+    density = ratio(m_e, m_v)
     density = jnp.maximum(density, core_density)
     return CBDSResult(
         density=density,

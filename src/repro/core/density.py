@@ -9,6 +9,44 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+_MANT = 1 << 24  # f32 significand range: integers below 2^24 are exact
+
+
+def ratio(num: jax.Array, den: jax.Array) -> jax.Array:
+    """``f32(num) / f32(max(den, 1))`` rounded to nearest-even on every
+    backend.
+
+    XLA's TPU backend does not round f32 division to nearest: on a v5e,
+    about a third of the quotients of random counts below 2^24, and a
+    quarter of the quotients that are exact, came back one ulp off the IEEE
+    quotient (3631/190 one ulp low). Every density, peel threshold and best-density comparison must
+    agree bit for bit with the CPU and the numpy reference: a low quotient
+    on an exact tie ``deg == 2·n_e/n_v`` fails no vertex of a regular
+    subgraph, and the peel would never end. So the quotient is formed by
+    integer long division: ``num`` (a nonnegative int32 count) is rounded
+    to f32 first, exactly as the float division did, and ``den`` is a
+    nonnegative int32 count below 2^24.
+    """
+    num = jnp.asarray(num, jnp.int32)
+    den = jnp.maximum(jnp.asarray(den, jnp.int32), 1)
+    mant, ex = jnp.frexp(num.astype(jnp.float32))
+    m = (mant * _MANT).astype(jnp.int32)   # f32(num) == m * 2^(ex - 24)
+    q = m // den
+    r = m - q * den
+    shift = jnp.zeros_like(q)
+    # m >= 2^23 and den < 2^24, so 26 doublings bring q to [2^24, 2^25):
+    # 24 significand bits plus one rounding bit, the rest sticky in r
+    for _ in range(26):
+        grow = q < _MANT
+        bit = (2 * r >= den).astype(jnp.int32)
+        q = jnp.where(grow, 2 * q + bit, q)
+        r = jnp.where(grow, 2 * r - bit * den, r)
+        shift = shift + grow.astype(jnp.int32)
+    sig = q >> 1
+    sig = sig + ((q & 1) & ((r != 0) | (sig & 1)).astype(jnp.int32))
+    out = jnp.ldexp(sig.astype(jnp.float32), ex - 23 - shift)
+    return jnp.where(num > 0, out, 0.0)
+
 
 def degrees_from_coo(src: jax.Array, n_nodes: int) -> jax.Array:
     """int32 [n_nodes] degrees from symmetric directed src array (padded)."""
@@ -39,7 +77,7 @@ def subgraph_density(src: jax.Array, dst: jax.Array, mask: jax.Array, n_nodes: i
     """rho(S) as float32; 0 for empty S."""
     ne = induced_edge_count(src, dst, mask, n_nodes)
     nv = jnp.sum(mask.astype(jnp.int32))
-    return jnp.where(nv > 0, ne.astype(jnp.float32) / jnp.maximum(nv, 1), 0.0)
+    return jnp.where(nv > 0, ratio(ne, nv), 0.0)
 
 
 def density_np(n_edges: int, n_nodes: int) -> float:
@@ -54,11 +92,11 @@ def check_approx_bound(approx: float, exact: float, alpha: float, tol: float = 1
 def peel_threshold(n_e: jax.Array, n_v: jax.Array, eps: float) -> jax.Array:
     """Bahmani peeling threshold 2(1+eps)·rho as float32 (see DESIGN §2 on
     precision: comparisons are float32; exact for bench-sized integer counts)."""
-    rho = n_e.astype(jnp.float32) / jnp.maximum(n_v.astype(jnp.float32), 1.0)
-    return 2.0 * (1.0 + eps) * rho
+    return 2.0 * (1.0 + eps) * ratio(n_e, n_v)
 
 
 __all__ = [
+    "ratio",
     "degrees_from_coo",
     "masked_degrees",
     "induced_edge_count",

@@ -16,9 +16,9 @@ implementations exist:
 ``kernel=`` knob is threaded (as a *static* jit argument — flipping it is a
 legitimate one-time compile, audited under its own shape key) from
 ``pbahmani`` / ``kcore_decompose`` / ``DeltaEngine`` / ``GraphRegistry`` /
-``StreamService`` down to here. ``kernel=None`` resolves to the deploy
-default: off on CPU (interpret-mode Pallas adds no arithmetic win), on when
-``PALLAS_INTERPRET=0`` says a real TPU lowers the kernel.
+``StreamService`` down to here. ``kernel=None`` resolves to the scatter tier
+on every platform; whether the kernel runs compiled or interpreted follows
+the platform (``kernels.segsum.interpret_default``), never a flag.
 
 Bit-identity argument (the invariant tests/test_oracle_properties.py and
 benchmarks/bench_kernels.py assert): both paths sum the same 0/1
@@ -31,8 +31,6 @@ a *performance* precondition: bands are recomputed from data every call).
 """
 from __future__ import annotations
 
-import os
-
 import jax
 import jax.numpy as jnp
 
@@ -40,19 +38,6 @@ import jax.numpy as jnp
 # stay strictly below 2^24 or float accumulation could round — the whole
 # bit-identity contract rests on this bound.
 EXACT_ENVELOPE = 1 << 24
-
-
-def kernel_default() -> bool:
-    """Deploy default for the ``kernel=`` knob: the Pallas path is on only
-    when ``PALLAS_INTERPRET=0`` declares a real TPU lowering (on CPU the
-    interpret-mode kernel is emulation — correct, measured by
-    bench_kernels.py, but not a win over the XLA scatter)."""
-    return os.environ.get("PALLAS_INTERPRET", "1") == "0"
-
-
-def resolve_kernel(kernel: bool | None) -> bool:
-    """``None`` -> environment default; anything else -> bool(kernel)."""
-    return kernel_default() if kernel is None else bool(kernel)
 
 
 # repro: proof
@@ -92,5 +77,5 @@ def peel_delta(
         num_segments=n_nodes + 1)[:n_nodes]
 
 
-__all__ = ["EXACT_ENVELOPE", "kernel_default", "resolve_kernel",
+__all__ = ["EXACT_ENVELOPE",
            "assert_exact_envelope", "peel_delta"]

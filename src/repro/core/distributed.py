@@ -29,9 +29,8 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core.pbahmani import PeelState, init_state
-from repro.core.density import peel_threshold
+from repro.core.density import peel_threshold, ratio
 from repro.graphs.graph import Graph
-from repro.utils.compat import shard_map_compat
 
 # jitted entry points created by the cached sharded factories below (and by
 # the sharded ingest in stream/delta.py and the sharded bucket peel in
@@ -130,9 +129,7 @@ def _peel_pass_body(state: PeelState, src_l, dst_l, n_nodes, eps,
     deg_new = jnp.where(active_new, state.deg - delta, 0).astype(jnp.int32)
     n_e_new = state.n_e - removed // 2
     n_v_new = state.n_v - jnp.sum(failed.astype(jnp.int32))
-    rho_new = jnp.where(
-        n_v_new > 0,
-        n_e_new.astype(jnp.float32) / jnp.maximum(n_v_new, 1), 0.0)
+    rho_new = jnp.where(n_v_new > 0, ratio(n_e_new, n_v_new), 0.0)
     better = rho_new > state.best_density
     return PeelState(
         deg=deg_new, active=active_new, n_v=n_v_new, n_e=n_e_new,
@@ -151,9 +148,9 @@ def make_peel_pass(mesh, n_nodes: int, eps: float):
 
     state_spec = PeelState(deg=P(), active=P(), n_v=P(), n_e=P(),
                            best_density=P(), best_mask=P(), passes=P())
-    return shard_map_compat(body, mesh=mesh,
-                            in_specs=(state_spec, P(axes), P(axes)),
-                            out_specs=state_spec, check_vma=False)
+    return jax.shard_map(body, mesh=mesh,
+                         in_specs=(state_spec, P(axes), P(axes)),
+                         out_specs=state_spec, check_vma=False)
 
 
 @lru_cache(maxsize=None)
@@ -177,7 +174,7 @@ def make_sharded_warm_peel(mesh, n_nodes: int, eps: float):
         live = valid & mask[src_c] & mask[dst_c]
         return jax.lax.psum(jnp.sum(live.astype(jnp.int32)), axes)
 
-    warm_count = shard_map_compat(
+    warm_count = jax.shard_map(
         warm_count_body, mesh=mesh, in_specs=(P(axes), P(axes), P()),
         out_specs=P(), check_vma=False)
 
@@ -186,7 +183,7 @@ def make_sharded_warm_peel(mesh, n_nodes: int, eps: float):
         active = deg > 0
         n_v = jnp.sum(active.astype(jnp.int32))
         n_e = n_edges.astype(jnp.int32)
-        rho0 = n_e.astype(jnp.float32) / jnp.maximum(n_v, 1).astype(jnp.float32)
+        rho0 = ratio(n_e, n_v)
         state = PeelState(
             deg=deg.astype(jnp.int32), active=active, n_v=n_v, n_e=n_e,
             best_density=rho0, best_mask=active,
@@ -195,9 +192,7 @@ def make_sharded_warm_peel(mesh, n_nodes: int, eps: float):
             lambda s: s.n_v > 0, lambda s: peel_pass(s, src, dst), state)
         warm_e = warm_count(src, dst, prev_mask) // 2
         warm_v = jnp.sum(prev_mask.astype(jnp.int32))
-        warm_rho = jnp.where(
-            warm_v > 0, warm_e.astype(jnp.float32) / jnp.maximum(warm_v, 1),
-            0.0)
+        warm_rho = jnp.where(warm_v > 0, ratio(warm_e, warm_v), 0.0)
         return final, warm_rho
 
     SHARDED_JITS.append(run)
@@ -212,7 +207,7 @@ def _warm_peel_shard_body(src_l, dst_l, deg, n_edges, prev_mask,
     active = deg > 0
     n_v = jnp.sum(active.astype(jnp.int32))
     n_e = n_edges.astype(jnp.int32)
-    rho0 = n_e.astype(jnp.float32) / jnp.maximum(n_v, 1).astype(jnp.float32)
+    rho0 = ratio(n_e, n_v)
     state = PeelState(
         deg=deg.astype(jnp.int32), active=active, n_v=n_v, n_e=n_e,
         best_density=rho0, best_mask=active,
@@ -226,8 +221,7 @@ def _warm_peel_shard_body(src_l, dst_l, deg, n_edges, prev_mask,
     live = valid & prev_mask[src_c] & prev_mask[dst_c]
     warm_e = jax.lax.psum(jnp.sum(live.astype(jnp.int32)), axes) // 2
     warm_v = jnp.sum(prev_mask.astype(jnp.int32))
-    warm_rho = jnp.where(
-        warm_v > 0, warm_e.astype(jnp.float32) / jnp.maximum(warm_v, 1), 0.0)
+    warm_rho = jnp.where(warm_v > 0, ratio(warm_e, warm_v), 0.0)
     return final, warm_rho
 
 
@@ -257,7 +251,7 @@ def make_sharded_batched_warm_peel(mesh, n_nodes: int, eps: float):
 
     state_spec = PeelState(deg=P(), active=P(), n_v=P(), n_e=P(),
                            best_density=P(), best_mask=P(), passes=P())
-    run = jax.jit(shard_map_compat(
+    run = jax.jit(jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(None, axes), P(None, axes), P(), P(), P()),
         out_specs=(state_spec, P()), check_vma=False))
@@ -333,9 +327,9 @@ def make_kcore_level(mesh, n_nodes: int):
         )
 
     spec = DistCoreState(*(P() for _ in DistCoreState._fields))
-    return shard_map_compat(body, mesh=mesh,
-                            in_specs=(spec, P(axes), P(axes)),
-                            out_specs=spec, check_vma=False)
+    return jax.shard_map(body, mesh=mesh,
+                         in_specs=(spec, P(axes), P(axes)),
+                         out_specs=spec, check_vma=False)
 
 
 @lru_cache(maxsize=None)
@@ -367,7 +361,7 @@ def _make_cbds_run(mesh, n_nodes: int, rounds: int):
         return (member_new, m_v + n_add,
                 m_e + inter_into + inter_cross, n_add)
 
-    augment = shard_map_compat(
+    augment = jax.shard_map(
         augment_body, mesh=mesh,
         in_specs=(P(), P(), P(), P(axes), P(axes)),
         out_specs=(P(), P(), P(), P()), check_vma=False)
@@ -381,8 +375,8 @@ def _make_cbds_run(mesh, n_nodes: int, rounds: int):
                 jnp.ones_like(src_l, jnp.int32), jnp.minimum(src_l, n),
                 num_segments=n + 1)[:n]
             return jax.lax.psum(d, axes)
-        deg = shard_map_compat(deg_body, mesh=mesh, in_specs=(P(axes),),
-                               out_specs=P(), check_vma=False)(src)
+        deg = jax.shard_map(deg_body, mesh=mesh, in_specs=(P(axes),),
+                            out_specs=P(), check_vma=False)(src)
         del ones
         s0 = DistCoreState(
             k=jnp.asarray(0, jnp.int32), deg=deg,
@@ -399,7 +393,7 @@ def _make_cbds_run(mesh, n_nodes: int, rounds: int):
             return s.n_v > 0
 
         def outer(s):
-            density = s.n_e.astype(jnp.float32) / jnp.maximum(s.n_v, 1)
+            density = ratio(s.n_e, s.n_v)
             better = (density > s.best_density) & (s.n_v > 0)
             s = s._replace(
                 best_density=jnp.where(better, density, s.best_density),
@@ -418,7 +412,7 @@ def _make_cbds_run(mesh, n_nodes: int, rounds: int):
         for _ in range(rounds):
             member, m_v, m_e, n_add = augment(member, m_v, m_e, src, dst)
             n_legit = n_legit + n_add
-        density = m_e.astype(jnp.float32) / jnp.maximum(m_v, 1)
+        density = ratio(m_e, m_v)
         return (core, member, jnp.maximum(density, core.best_density),
                 n_legit)
 

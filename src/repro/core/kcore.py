@@ -21,8 +21,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core.density import ratio
 from repro.core.dispatch import (
-    assert_exact_envelope, peel_delta, resolve_kernel,
+    assert_exact_envelope, peel_delta,
 )
 from repro.graphs.graph import Graph
 
@@ -100,7 +101,7 @@ def _kcore_jit(
     def body(s: CoreState) -> CoreState:
         # graph remaining on *entry* to level k is the k-core; record its
         # density (paper Alg. 2, the `single` block after each level).
-        density = s.n_e.astype(jnp.float32) / jnp.maximum(s.n_v, 1).astype(jnp.float32)
+        density = ratio(s.n_e, s.n_v)
         better = (density > s.best_density) & (s.n_v > 0)
         s = s._replace(
             best_density=jnp.where(better, density, s.best_density),
@@ -125,7 +126,7 @@ def kcore_decompose(
     kernel mode feeds the cached dst-sorted view so the band-skip
     precondition holds — identical outputs either way.
     """
-    kernel = resolve_kernel(kernel)
+    kernel = bool(kernel)
     if kernel:
         assert_exact_envelope(graph.src.shape[0], graph.n_nodes)
         src_h, dst_h = graph.dst_sorted()

@@ -20,9 +20,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.density import peel_threshold
+from repro.core.density import peel_threshold, ratio
 from repro.core.dispatch import (
-    assert_exact_envelope, peel_delta, resolve_kernel,
+    assert_exact_envelope, peel_delta,
 )
 from repro.graphs.graph import Graph
 
@@ -54,7 +54,7 @@ def init_state(src: jax.Array, dst: jax.Array, n_nodes: int, n_edges: int) -> Pe
     active = deg > 0  # isolated vertices never contribute to density
     n_v = jnp.sum(active.astype(jnp.int32))
     n_e = jnp.asarray(n_edges, jnp.int32)
-    rho0 = n_e.astype(jnp.float32) / jnp.maximum(n_v, 1).astype(jnp.float32)
+    rho0 = ratio(n_e, n_v)
     return PeelState(
         deg=deg.astype(jnp.int32),
         active=active,
@@ -100,8 +100,7 @@ def pbahmani_pass(
     deg_new = jnp.where(active_new, state.deg - delta_to_dst, 0).astype(jnp.int32)
     n_v_new = state.n_v - jnp.sum(failed.astype(jnp.int32))
 
-    rho_new = n_e_new.astype(jnp.float32) / jnp.maximum(n_v_new, 1).astype(jnp.float32)
-    rho_new = jnp.where(n_v_new > 0, rho_new, 0.0)
+    rho_new = jnp.where(n_v_new > 0, ratio(n_e_new, n_v_new), 0.0)
     better = rho_new > state.best_density
     best_density = jnp.where(better, rho_new, state.best_density)
     best_mask = jnp.where(better, active_new, state.best_mask)
@@ -154,8 +153,8 @@ def pbahmani(
     certificate and the anytime ``target_gap`` loop. ``passes`` then counts
     the seed peel's passes plus every refinement round's.
 
-    ``kernel=None`` resolves to the deploy default (on iff
-    ``PALLAS_INTERPRET=0``); ``True`` forces the Pallas segment-sum tier —
+    ``kernel=None`` resolves to the scatter tier; ``True`` selects the
+    Pallas segment-sum tier (compiled on a TPU, interpreted elsewhere) —
     the edge lanes are then fed from ``graph.dst_sorted()`` (the cached
     host-side sort) so the kernel's band-skip precondition holds without
     any in-jit argsort, and the triple is bit-identical to the scatter
@@ -163,7 +162,7 @@ def pbahmani(
     """
     if graph.n_nodes == 0:
         return 0.0, np.zeros(0, dtype=bool), 0
-    kernel = resolve_kernel(kernel)
+    kernel = bool(kernel)
     if kernel:
         assert_exact_envelope(graph.src.shape[0], graph.n_nodes)
     if pruned:

@@ -72,8 +72,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from repro.core.density import degrees_from_coo, subgraph_density
-from repro.core.dispatch import assert_exact_envelope, resolve_kernel
+from repro.core.density import degrees_from_coo, ratio, subgraph_density
+from repro.core.dispatch import assert_exact_envelope
 from repro.core.distributed import (
     DistCoreState, SHARDED_JITS, _peel_pass_body, edge_sharding,
     make_kcore_level, make_peel_pass, mesh_device_count,
@@ -82,8 +82,6 @@ from repro.core.kcore import CoreState, _level_fixpoint
 from repro.core.pbahmani import PeelState, pbahmani_pass
 from repro.graphs.graph import Graph
 from repro.kernels.compact import stream_compact
-from repro.kernels.ops import _INTERPRET
-from repro.utils.compat import shard_map_compat
 from repro.utils.num import next_pow2
 
 MIN_BUCKET_V = 64     # smallest compacted vertex space (pow-2 buckets above)
@@ -153,7 +151,7 @@ def _plan_jit(
     active = deg > 0
     n_v = jnp.sum(active.astype(jnp.int32))
     n_e = n_edges.astype(jnp.int32)
-    rho0 = n_e.astype(jnp.float32) / jnp.maximum(n_v, 1).astype(jnp.float32)
+    rho0 = ratio(n_e, n_v)
     # previous epoch's best mask, re-evaluated on the current edges: a sound
     # warm start for rho~ even after deletions (it is a *current* subgraph)
     warm_rho = subgraph_density(src, dst, prev_mask, n_nodes)
@@ -179,11 +177,7 @@ def _plan_jit(
     def body(c: CoreState) -> CoreState:
         c = c._replace(k=_ceil_level(c.best_density) - 1)
         c = _level_fixpoint(c, src, dst, n_nodes, kernel)  # kcore sweep
-        rho_c = jnp.where(
-            c.n_v > 0,
-            c.n_e.astype(jnp.float32) / jnp.maximum(c.n_v, 1).astype(jnp.float32),
-            0.0,
-        )
+        rho_c = jnp.where(c.n_v > 0, ratio(c.n_e, c.n_v), 0.0)
         return c._replace(best_density=jnp.maximum(c.best_density, rho_c))
 
     final = jax.lax.while_loop(cond, body, state)
@@ -211,7 +205,7 @@ def make_sharded_plan(mesh, n_nodes: int):
         warm_cnt = jax.lax.psum(jnp.sum(live.astype(jnp.int32)), axes)
         return deg, warm_cnt
 
-    stats = shard_map_compat(
+    stats = jax.shard_map(
         stats_body, mesh=mesh, in_specs=(P(axes), P(axes), P()),
         out_specs=(P(), P()), check_vma=False)
 
@@ -226,12 +220,10 @@ def make_sharded_plan(mesh, n_nodes: int):
         active = deg > 0
         n_v = jnp.sum(active.astype(jnp.int32))
         n_e = n_edges.astype(jnp.int32)
-        rho0 = n_e.astype(jnp.float32) / jnp.maximum(n_v, 1).astype(jnp.float32)
+        rho0 = ratio(n_e, n_v)
         warm_v = jnp.sum(prev_mask.astype(jnp.int32))
         warm_e = warm_cnt // 2
-        warm_rho = jnp.where(
-            warm_v > 0, warm_e.astype(jnp.float32) / jnp.maximum(warm_v, 1),
-            0.0)
+        warm_rho = jnp.where(warm_v > 0, ratio(warm_e, warm_v), 0.0)
         rho_lb = jnp.maximum(rho0, warm_rho)
         state = DistCoreState(
             k=jnp.asarray(-1, jnp.int32),
@@ -254,12 +246,7 @@ def make_sharded_plan(mesh, n_nodes: int):
             c = jax.lax.while_loop(
                 lambda t: jnp.any(t.active & (t.deg <= t.k)),
                 lambda t: level(t, src, dst), c)
-            rho_c = jnp.where(
-                c.n_v > 0,
-                c.n_e.astype(jnp.float32)
-                / jnp.maximum(c.n_v, 1).astype(jnp.float32),
-                0.0,
-            )
+            rho_c = jnp.where(c.n_v > 0, ratio(c.n_e, c.n_v), 0.0)
             return c._replace(best_density=jnp.maximum(c.best_density, rho_c))
 
         final = jax.lax.while_loop(cond, body, state)
@@ -387,7 +374,7 @@ def _compact_edges(
             jnp.stack(
                 [perm[src_c].astype(jnp.int32), perm[dst_c].astype(jnp.int32)],
                 axis=1),
-            live, out_size=bucket_e, fill=bucket_v, interpret=_INTERPRET)
+            live, out_size=bucket_e, fill=bucket_v)
         return perm, packed[:, 0], packed[:, 1]
     live_i = live.astype(jnp.int32)
     pos = jnp.where(live, jnp.cumsum(live_i) - 1, bucket_e)
@@ -441,8 +428,7 @@ def _staged_peel(
         # survivors land as a dense prefix, so the live mask is arange<n_v
         # and the degree pull is the same stream compaction (fill = 0 ==
         # what the scatter writes in dead slots) — bit-identical arrays
-        b_deg = stream_compact(s1.deg, s1.active, out_size=bucket_v, fill=0,
-                               interpret=_INTERPRET)
+        b_deg = stream_compact(s1.deg, s1.active, out_size=bucket_v, fill=0)
         b_active = jnp.arange(bucket_v, dtype=jnp.int32) < s1.n_v
     else:
         vslot = jnp.where(s1.active, perm, bucket_v)
@@ -576,8 +562,8 @@ def _make_sharded_bucket_peel(mesh, eps: float, bucket_v: int, bucket_e: int,
             num_segments=bucket_v + 1)[:bucket_v]
         return jax.lax.psum(d, axes)
 
-    deg_hist = shard_map_compat(deg_body, mesh=mesh, in_specs=(P(axes),),
-                                out_specs=P(), check_vma=False)
+    deg_hist = jax.shard_map(deg_body, mesh=mesh, in_specs=(P(axes),),
+                             out_specs=P(), check_vma=False)
 
     def compact_body(src_l, dst_l, live_v):
         src_c = jnp.minimum(src_l, bucket_v - 1)
@@ -593,7 +579,7 @@ def _make_sharded_bucket_peel(mesh, eps: float, bucket_v: int, bucket_e: int,
             perm[dst_c].astype(jnp.int32), mode="drop")
         return b_src, b_dst
 
-    compact = shard_map_compat(
+    compact = jax.shard_map(
         compact_body, mesh=mesh, in_specs=(P(axes), P(axes), P()),
         out_specs=(P(axes), P(axes)), check_vma=False)
 
@@ -716,7 +702,7 @@ def _make_sharded_batched_bucket_peel(mesh, eps: float, bucket_v: int,
             lambda s, d, v, e, bd, p: tenant(s, d, v, e, bd, p)
         )(b_src_l, b_dst_l, n_v, n_e, best_density, passes)
 
-    run = jax.jit(shard_map_compat(
+    run = jax.jit(jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(None, axes), P(None, axes), P(), P(), P(), P()),
         out_specs=(P(), P(), P()), check_vma=False))
@@ -980,7 +966,7 @@ def pbahmani_pruned(
     (density, mask AND pass count), at bucket-width device cost. ``kernel``
     selects the Pallas segment-sum tier for the bucket peel (None = deploy
     default) — same triple either way."""
-    kernel = resolve_kernel(kernel)
+    kernel = bool(kernel)
     if plan is None:
         plan = plan_for_graph(graph, kernel=kernel)
     if not plan.enabled or graph.n_nodes == 0:

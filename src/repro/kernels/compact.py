@@ -31,7 +31,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.segsum import _CompilerParams, _round_up, segment_sum_sorted
+from repro.kernels.segsum import (
+    _round_up, interpret_default, segment_sum_sorted,
+)
 
 P_TILE = 512  # lanes per scan tile (lane-aligned, MXU contraction dim)
 
@@ -58,13 +60,16 @@ def _prefix_kernel(x_ref, out_ref, carry_ref):
 
 # repro: unaudited -- kernel-tier primitive; audited indirectly through the engine jits that inline it (delta/refine providers), counting it here would double-book
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def prefix_sum(x: jax.Array, *, interpret: bool = True) -> jax.Array:
+def prefix_sum(x: jax.Array, *, interpret: bool | None = None) -> jax.Array:
     """Inclusive prefix sum of a 1-D int32/bool array, exact int32 out.
 
     Exactness: the scan runs in float32, so the total must stay under the
     2^24 integer envelope — true for every caller (counts bounded by edge
     capacities, asserted at plan build via ``core.dispatch``).
+    ``interpret=None`` follows the platform (``interpret_default``).
     """
+    if interpret is None:
+        interpret = interpret_default()
     (e,) = x.shape
     e_pad = _round_up(max(e, 1), P_TILE)
     xf = jnp.zeros((e_pad,), jnp.float32).at[:e].set(x.astype(jnp.float32))
@@ -73,13 +78,16 @@ def prefix_sum(x: jax.Array, *, interpret: bool = True) -> jax.Array:
     out = pl.pallas_call(
         _prefix_kernel,
         grid=(n_tiles,),
-        in_specs=[pl.BlockSpec((1, P_TILE), lambda j: (j, 0))],
-        out_specs=pl.BlockSpec((1, P_TILE), lambda j: (j, 0)),
-        out_shape=jax.ShapeDtypeStruct((n_tiles, P_TILE), jnp.float32),
+        # tile axis squeezed out of the block (see segsum.py): the TPU
+        # lowering refuses a (1, P_TILE) block over more than one row
+        in_specs=[pl.BlockSpec((None, 1, P_TILE), lambda j: (j, 0, 0))],
+        out_specs=pl.BlockSpec((None, 1, P_TILE), lambda j: (j, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((n_tiles, 1, P_TILE), jnp.float32),
         scratch_shapes=[pltpu.SMEM((1, 1), jnp.float32)],
-        compiler_params=_CompilerParams(dimension_semantics=("arbitrary",)),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
-    )(xf.reshape(n_tiles, P_TILE))
+    )(xf.reshape(n_tiles, 1, P_TILE))
     return out.reshape(-1)[:e].astype(jnp.int32)
 
 
@@ -91,7 +99,7 @@ def stream_compact(
     *,
     out_size: int,
     fill: int,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     """Compact ``values[live]`` into a dense ``[out_size]`` (or
     ``[out_size, D]``) int32 array, empty slots = ``fill``.

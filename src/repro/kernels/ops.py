@@ -1,8 +1,8 @@
 """Jit'd public wrappers around the Pallas segment-sum core.
 
 Every op takes ``impl=`` selecting the backend:
-  * ``"pallas"``  — the TPU kernel (interpret=True on CPU; the deploy path
-                    flips interpret off via ``PALLAS_INTERPRET``).
+  * ``"pallas"``  — the TPU kernel (compiled on a TPU, interpret mode
+                    elsewhere: ``kernels.segsum.interpret_default``).
   * ``"xla"``     — the pure-jnp oracle (ref.py); used by the 512-device
                     dry-run so the lowered HLO stays backend-portable.
 
@@ -18,7 +18,6 @@ were exactly the bug that kept the kernel tier off the hot path (ISSUE 7).
 from __future__ import annotations
 
 import contextlib
-import os
 from functools import partial
 
 import jax
@@ -26,10 +25,6 @@ import jax.numpy as jnp
 
 from repro.kernels import ref as _ref
 from repro.kernels.segsum import segment_sum_sorted
-from repro.utils.compat import shard_map_compat
-
-# interpret=True everywhere except a real TPU deployment.
-_INTERPRET = os.environ.get("PALLAS_INTERPRET", "1") != "0"
 
 # ---------------------------------------------------------------------------
 # vertex-partitioned aggregation hint (EXPERIMENTS.md §Perf hillclimb #2):
@@ -113,7 +108,7 @@ def vp_segment_sum(values: jax.Array, seg_ids: jax.Array, num_segments: int):
             out = jax.lax.psum(out, a)
         return out
 
-    out = shard_map_compat(
+    out = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(all_axes, None), P(all_axes)),
         out_specs=P(node_axes, None),
@@ -154,9 +149,7 @@ def _segment_sum_jit(
         order = jnp.argsort(seg_ids)
         seg_ids = jnp.take(seg_ids, order)
         values = jnp.take(values, order, axis=0)
-    return segment_sum_sorted(
-        values, seg_ids, num_segments=num_segments, interpret=_INTERPRET
-    )
+    return segment_sum_sorted(values, seg_ids, num_segments=num_segments)
 
 
 def segment_sum(
@@ -197,8 +190,7 @@ def _peel_update_jit(
         order = jnp.argsort(dst)
         dst = jnp.take(dst, order)
         vals = jnp.take(vals, order)
-    out = segment_sum_sorted(vals, dst, num_segments=n_nodes,
-                             interpret=_INTERPRET)
+    out = segment_sum_sorted(vals, dst, num_segments=n_nodes)
     # the peel recurrence is int32 (exact counts < 2^24 — asserted at plan
     # build by core.dispatch.assert_exact_envelope); cast at the op
     # boundary so kernel-path degrees are bit-identical to the scatter path
@@ -248,7 +240,7 @@ def _segment_embed_jit(
         order = jnp.argsort(seg_ids)
         seg_ids = jnp.take(seg_ids, order)
         rows = jnp.take(rows, order, axis=0)
-    return segment_sum_sorted(rows, seg_ids, num_segments=num_segments, interpret=_INTERPRET)
+    return segment_sum_sorted(rows, seg_ids, num_segments=num_segments)
 
 
 def segment_embed(

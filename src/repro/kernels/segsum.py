@@ -36,9 +36,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams; support both
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 V_TILE = 256  # output rows per block     (multiple of 8 sublanes & 128 MXU)
 E_TILE = 512  # edges per tile            (lane-aligned, contraction dim)
 
@@ -62,12 +59,23 @@ def _segsum_kernel(band_lo_ref, band_hi_ref, seg_ref, val_ref, out_ref):
         rows = jax.lax.broadcasted_iota(jnp.int32, (V_TILE, E_TILE), 0)
         onehot = (rows == local[None, :]).astype(jnp.float32)
         # MXU: (V_TILE, E_TILE) @ (E_TILE, D) — the deterministic "atomic add"
-        part = jnp.dot(onehot, val_ref[...], preferred_element_type=jnp.float32)
+        # HIGHEST: values can be vertex ids (stream_compact), and a
+        # default-precision f32 dot on the TPU rounds its operands to bf16,
+        # which is exact only for integers up to 256
+        part = jnp.dot(onehot, val_ref[...],
+                       precision=jax.lax.Precision.HIGHEST,
+                       preferred_element_type=jnp.float32)
         out_ref[...] += part
 
 
 def _round_up(x: int, m: int) -> int:
     return ((x + m - 1) // m) * m
+
+
+def interpret_default() -> bool:
+    """Pallas interpret mode off the TPU, compiled kernels on it: the
+    platform decides, so a kernel never runs interpreted on a chip."""
+    return jax.default_backend() != "tpu"
 
 
 # repro: unaudited -- kernel-tier primitive; inlined into audited engine jits when called under trace
@@ -77,7 +85,7 @@ def segment_sum_sorted(
     seg_ids: jax.Array,
     *,
     num_segments: int,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     """Blocked segment-sum for edges **sorted by seg_ids**.
 
@@ -85,11 +93,13 @@ def segment_sum_sorted(
       values:   [E, D] float32/bfloat16 (or [E] — treated as D=1).
       seg_ids:  [E] int32, sorted ascending; ids >= num_segments are padding.
       num_segments: output rows V.
-      interpret: run the kernel body in interpret mode (CPU validation; the
-        TPU deployment flips this to False).
+      interpret: run the kernel body in interpret mode; ``None`` follows the
+        platform (:func:`interpret_default`).
 
     Returns [num_segments, D] (or [num_segments] for 1-D values), float32.
     """
+    if interpret is None:
+        interpret = interpret_default()
     squeeze = values.ndim == 1
     if squeeze:
         values = values[:, None]
@@ -109,11 +119,13 @@ def segment_sum_sorted(
 
     n_eb = e_pad // E_TILE
     n_vb = v_pad // V_TILE
-    seg_2d = seg_p.reshape(n_eb, E_TILE)
+    # [n_eb, 1, E_TILE] with the tile axis squeezed out of the block: the
+    # TPU lowering refuses a (1, E_TILE) block over more than one row
+    seg_3d = seg_p.reshape(n_eb, 1, E_TILE)
 
     # scalar-prefetch band table: vertex-block range each edge tile touches
-    band_lo = (jnp.min(seg_2d, axis=1) // V_TILE).astype(jnp.int32)
-    band_hi = (jnp.max(seg_2d, axis=1) // V_TILE).astype(jnp.int32)
+    band_lo = (jnp.min(seg_3d, axis=(1, 2)) // V_TILE).astype(jnp.int32)
+    band_hi = (jnp.max(seg_3d, axis=(1, 2)) // V_TILE).astype(jnp.int32)
 
     out = pl.pallas_call(
         _segsum_kernel,
@@ -121,20 +133,21 @@ def segment_sum_sorted(
             num_scalar_prefetch=2,  # band_lo, band_hi
             grid=(n_vb, n_eb),
             in_specs=[
-                pl.BlockSpec((1, E_TILE), lambda i, j, lo, hi: (j, 0)),
+                pl.BlockSpec((None, 1, E_TILE),
+                             lambda i, j, lo, hi: (j, 0, 0)),
                 pl.BlockSpec((E_TILE, d_pad), lambda i, j, lo, hi: (j, 0)),
             ],
             out_specs=pl.BlockSpec((V_TILE, d_pad), lambda i, j, lo, hi: (i, 0)),
         ),
         out_shape=jax.ShapeDtypeStruct((v_pad, d_pad), jnp.float32),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")
         ),
         interpret=interpret,
-    )(band_lo, band_hi, seg_2d, vals_p)
+    )(band_lo, band_hi, seg_3d, vals_p)
 
     out = out[:num_segments, :d]
     return out[:, 0] if squeeze else out
 
 
-__all__ = ["segment_sum_sorted", "V_TILE", "E_TILE"]
+__all__ = ["segment_sum_sorted", "interpret_default", "V_TILE", "E_TILE"]

@@ -7,7 +7,7 @@ from __future__ import annotations
 
 import jax
 
-from repro.utils.compat import make_mesh_auto
+from repro.utils.mesh import make_mesh_auto
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -31,7 +31,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.utils.compat import shard_map_compat
 
 
 @dataclass(frozen=True)
@@ -184,7 +183,7 @@ def moe_ep(x: jax.Array, p: dict, cfg: MoEConfig, *, mesh=None,
             return y2d.reshape(bl, sl, d), aux_l
 
         spec_x = P(dp, tp if sp else None, None)
-        y, aux = shard_map_compat(
+        y, aux = jax.shard_map(
             body, mesh=mesh,
             in_specs=(spec_x, P(), P(tp, None, None), P(tp, None, None),
                       P(tp, None, None)),

@@ -13,7 +13,6 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.models.moe import MoEConfig, _route, _shared_ffn
-from repro.utils.compat import shard_map_compat
 
 
 def _moe_tp_local(x2d, router, wg, wi, wo, cfg: MoEConfig, axis: str | None):
@@ -69,7 +68,7 @@ def moe_tp(x: jax.Array, p: dict, cfg: MoEConfig, *, mesh=None,
             return y2d.reshape(bl, sl, d), aux_l
 
         spec_x = P(dp, None, None)
-        y, aux = shard_map_compat(
+        y, aux = jax.shard_map(
             body, mesh=mesh,
             in_specs=(spec_x, P(), P(None, None, tp), P(None, None, tp),
                       P(None, tp, None)),

@@ -37,11 +37,6 @@ from dataclasses import dataclass, field
 
 from repro.obs.metrics import MetricsRegistry
 
-try:  # the profiler bridge is optional: absent on stripped-down jax builds
-    from jax.profiler import TraceAnnotation as _TraceAnnotation
-except Exception:  # pragma: no cover
-    _TraceAnnotation = None
-
 # span attribute -> metrics-registry series fed on exit (labeled like the
 # span). Counters accumulate ints; gauges keep the last value.
 ATTR_COUNTERS = {
@@ -58,6 +53,7 @@ ATTR_GAUGES = {
 ATTR_FLAG_COUNTERS = {  # truthy attr -> counter += 1
     "certified_skip": "certified_skips_total",
     "compiled": "first_calls_total",
+    "flush_fallback": "flush_fallback_total",
 }
 
 
@@ -140,8 +136,12 @@ class Span:
             self.parent_id = stack[-1].span_id
             self.depth = len(stack)
         stack.append(self)
-        if self.tracer.profiler_bridge and _TraceAnnotation is not None:
-            self._ann = _TraceAnnotation(f"obs:{self.name}")
+        if self.tracer.profiler_bridge:
+            # imported here, not at module top: a process that only
+            # collects telemetry (the collector parent) never imports jax
+            from jax.profiler import TraceAnnotation
+
+            self._ann = TraceAnnotation(f"obs:{self.name}")
             self._ann.__enter__()
         self._wall = time.time()
         self._t0 = time.perf_counter()

@@ -167,9 +167,9 @@ def refine(
     ``kernel`` selects the Pallas segment-sum tier (None = deploy default);
     kernel mode feeds ``graph.dst_sorted()`` lanes — same certificates.
     """
-    from repro.core.dispatch import assert_exact_envelope, resolve_kernel
+    from repro.core.dispatch import assert_exact_envelope
 
-    kernel = resolve_kernel(kernel)
+    kernel = bool(kernel)
     n = graph.n_nodes
     # refine_resident's kernel tier accumulates failed-neighbor counts in
     # f32 lanes — exact only below 2^24 (core/dispatch.py)

@@ -53,9 +53,9 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from repro.core.density import ratio
 from repro.core.dispatch import peel_delta
 from repro.core.distributed import SHARDED_JITS
-from repro.utils.compat import shard_map_compat
 
 
 class RefinePeelState(NamedTuple):
@@ -91,17 +91,13 @@ def refine_threshold(load_sum: jax.Array, n_e: jax.Array, n_v: jax.Array,
                      eps: float) -> jax.Array:
     """(1+eps) * average key over live vertices, float32. Shared verbatim by
     the COO and dense pass bodies so their trajectories stay bit-identical."""
-    avg = (load_sum + 2 * n_e).astype(jnp.float32) / jnp.maximum(
-        n_v, 1).astype(jnp.float32)
-    return (1.0 + eps) * avg
+    return (1.0 + eps) * ratio(load_sum + 2 * n_e, n_v)
 
 
 def _fold_best(state: RefinePeelState, n_e_new, n_v_new, active_new):
     """Strict-> best tracking off the new live set (f32 compare, exact ints
     carried alongside for the certificate)."""
-    rho_new = n_e_new.astype(jnp.float32) / jnp.maximum(n_v_new, 1).astype(
-        jnp.float32)
-    rho_new = jnp.where(n_v_new > 0, rho_new, 0.0)
+    rho_new = jnp.where(n_v_new > 0, ratio(n_e_new, n_v_new), 0.0)
     better = rho_new > state.best_density
     return (
         jnp.where(better, rho_new, state.best_density),
@@ -319,7 +315,7 @@ def _make_sharded_refine_round(mesh, n_nodes: int, eps: float):
             src_l, dst_l, deg, n_edges, loads, bd, be, bv, bm, ps,
             n_nodes, eps, axes)
 
-    run = jax.jit(shard_map_compat(
+    run = jax.jit(jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(axes), P(axes)) + (P(),) * 8,
         out_specs=(P(),) * 6, check_vma=False))
@@ -342,7 +338,7 @@ def _make_sharded_batched_refine_round(mesh, n_nodes: int, eps: float):
                 s, d, g, ne, lo, b1, b2, b3, b4, p, n_nodes, eps, axes)
         )(src_l, dst_l, deg, n_edges, loads, bd, be, bv, bm, ps)
 
-    run = jax.jit(shard_map_compat(
+    run = jax.jit(jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(None, axes), P(None, axes)) + (P(),) * 8,
         out_specs=(P(),) * 6, check_vma=False))
@@ -370,8 +366,11 @@ def _dense_refine_pass(state: RefinePeelState, adj: jax.Array,
     f = failed.astype(jnp.float32)
     a = state.active.astype(jnp.float32)
     af = adj @ f  # failing-neighbor counts (exact integers)
+    # HIGHEST on the vdots over counts (> 256): see stream/fused._dense_pass
+    hi = jax.lax.Precision.HIGHEST
     removed_directed = (
-        2.0 * jnp.vdot(f, adj @ a) - jnp.vdot(f, af)).astype(jnp.int32)
+        2.0 * jnp.vdot(f, adj @ a, precision=hi)
+        - jnp.vdot(f, af, precision=hi)).astype(jnp.int32)
     n_e_new = state.n_e - removed_directed // 2
     active_new = state.active & ~failed
     deg_new = jnp.where(active_new, state.deg - af.astype(jnp.int32), 0)

@@ -74,8 +74,8 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from repro.core.cbds import _cbds_jit
-from repro.core.density import induced_edge_count
-from repro.core.dispatch import assert_exact_envelope, resolve_kernel
+from repro.core.density import induced_edge_count, ratio
+from repro.core.dispatch import assert_exact_envelope
 from repro.core.distributed import (
     SHARDED_JITS, _make_cbds_run, flat_shard_index, make_sharded_warm_peel,
     mesh_device_count, validate_stream_mesh,
@@ -91,7 +91,7 @@ from repro.refine.certify import GapCertificate, make_certificate
 from repro.refine.engine import DEFAULT_TARGET_GAP, refine_resident
 from repro.refine.loads import REFINE_JITS
 from repro.stream.buffer import EdgeBuffer, MIN_CAPACITY, next_pow2
-from repro.utils.compat import make_mesh_auto, shard_map_compat
+from repro.utils.mesh import make_mesh_auto
 
 MIN_BATCH = 64  # smallest padded update-batch shape (pow-2 buckets above)
 DELETE_STALENESS_WEIGHT = 3.0  # an all-delete batch ages the epoch 4x
@@ -154,7 +154,7 @@ def _make_sharded_resync(mesh):
     def body(src_l, dst_l, deg, mask):
         return src_l, dst_l, deg, mask
 
-    run = jax.jit(shard_map_compat(
+    run = jax.jit(jax.shard_map(
         body, mesh=mesh, in_specs=(P(axes), P(axes), P(), P()),
         out_specs=(P(axes), P(axes), P(), P()), check_vma=False))
     SHARDED_JITS.append(run)
@@ -167,7 +167,7 @@ def _make_sharded_mask_sync(mesh):
     rationale as ``_make_sharded_resync``, for the pruned path's host-built
     prev mask (a raw ``jnp.asarray`` would carry a different sharding into
     the plan/warm-peel cache keys and silently recompile them)."""
-    run = jax.jit(shard_map_compat(
+    run = jax.jit(jax.shard_map(
         lambda m: m, mesh=mesh, in_specs=(P(),), out_specs=P(),
         check_vma=False))
     SHARDED_JITS.append(run)
@@ -212,7 +212,7 @@ def _make_sharded_apply(mesh, n_nodes: int):
         deg = (deg + d).astype(jnp.int32)
         return src_l, dst_l, deg
 
-    run = jax.jit(shard_map_compat(
+    run = jax.jit(jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(axes), P(axes), P(), P(), P(), P(), P(), P(), P()),
         out_specs=(P(axes), P(axes), P()), check_vma=False))
@@ -261,7 +261,7 @@ def _make_sharded_batched_apply(mesh, n_nodes: int):
 
         return jax.vmap(one)(src_l, dst_l, deg, slots, su, sv, du, dv, w)
 
-    run = jax.jit(shard_map_compat(
+    run = jax.jit(jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(None, axes), P(None, axes), P(), P(), P(), P(), P(),
                   P(), P()),
@@ -280,7 +280,7 @@ def _make_sharded_stack_sync(mesh):
     """Identity placement for (src, dst, deg, prev_mask) stacks — the
     alloc/grow upload path of a sharded TenantBatch."""
     axes = tuple(mesh.axis_names)
-    run = jax.jit(shard_map_compat(
+    run = jax.jit(jax.shard_map(
         lambda s, d, g, m: (s, d, g, m), mesh=mesh,
         in_specs=(P(None, axes), P(None, axes), P(), P()),
         out_specs=(P(None, axes), P(None, axes), P(), P()),
@@ -300,7 +300,7 @@ def _make_sharded_lane_write(mesh):
         return (src.at[lane].set(r_src), dst.at[lane].set(r_dst),
                 deg.at[lane].set(r_deg), mask.at[lane].set(r_mask))
 
-    run = jax.jit(shard_map_compat(
+    run = jax.jit(jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(None, axes), P(None, axes), P(), P(), P(),
                   P(axes), P(axes), P(), P()),
@@ -319,7 +319,7 @@ def _make_sharded_lane_gather(mesh):
     def body(src, dst, deg, mask, lanes):
         return src[lanes], dst[lanes], deg[lanes], mask[lanes]
 
-    run = jax.jit(shard_map_compat(
+    run = jax.jit(jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(None, axes), P(None, axes), P(), P(), P()),
         out_specs=(P(None, axes), P(None, axes), P(), P()),
@@ -339,7 +339,7 @@ def _make_sharded_row_view(mesh):
     def body(src, dst, deg, mask, lane):
         return src[lane], dst[lane], deg[lane], mask[lane]
 
-    run = jax.jit(shard_map_compat(
+    run = jax.jit(jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(None, axes), P(None, axes), P(), P(), P()),
         out_specs=(P(axes), P(axes), P(), P()), check_vma=False))
@@ -351,7 +351,7 @@ def _make_sharded_row_view(mesh):
 def _make_sharded_mask_rows_write(mesh):
     """Scatter per-tenant result masks back into the replicated prev-mask
     stack (OOB pad lanes drop, as in ``_mask_rows_write_jit``)."""
-    run = jax.jit(shard_map_compat(
+    run = jax.jit(jax.shard_map(
         lambda ms, lanes, masks: ms.at[lanes].set(masks, mode="drop"),
         mesh=mesh, in_specs=(P(), P(), P()), out_specs=P(),
         check_vma=False))
@@ -363,7 +363,7 @@ def _make_sharded_mask_rows_write(mesh):
 def _make_sharded_deg_rows_gather(mesh):
     """Gather degree rows for a group of lanes (replicated stack — the
     pruned-flush host prepare reads degrees per member)."""
-    run = jax.jit(shard_map_compat(
+    run = jax.jit(jax.shard_map(
         lambda stack, lanes: stack[lanes], mesh=mesh,
         in_specs=(P(), P()), out_specs=P(), check_vma=False))
     SHARDED_JITS.append(run)
@@ -447,7 +447,7 @@ def _warm_peel_body(
     active = deg > 0
     n_v = jnp.sum(active.astype(jnp.int32))
     n_e = n_edges.astype(jnp.int32)
-    rho0 = n_e.astype(jnp.float32) / jnp.maximum(n_v, 1).astype(jnp.float32)
+    rho0 = ratio(n_e, n_v)
     state = PeelState(
         deg=deg.astype(jnp.int32),
         active=active,
@@ -464,9 +464,7 @@ def _warm_peel_body(
     )
     warm_e = induced_edge_count(src, dst, prev_mask, n_nodes)
     warm_v = jnp.sum(prev_mask.astype(jnp.int32))
-    warm_rho = jnp.where(
-        warm_v > 0, warm_e.astype(jnp.float32) / jnp.maximum(warm_v, 1), 0.0
-    )
+    warm_rho = jnp.where(warm_v > 0, ratio(warm_e, warm_v), 0.0)
     return final, warm_rho
 
 
@@ -604,11 +602,11 @@ class DeltaEngine:
         self.refresh_every = int(refresh_every)
         self.pruned = bool(pruned)
         self.sharded = bool(sharded)
-        # kernel=None resolves to the deploy default (PALLAS_INTERPRET=0);
+        # kernel=None resolves to the scatter tier;
         # sharded engines stay on per-shard scatter — their lanes are
         # mesh-partitioned, not band-local, so the sorted-view machinery
         # below does not apply (ROADMAP follow-up)
-        self.kernel = resolve_kernel(kernel) and not self.sharded
+        self.kernel = bool(kernel) and not self.sharded
         # observability identity: the registry overwrites ``tenant`` with the
         # registered name; spans and audit records are labeled with it
         self.tenant = "-"

@@ -64,7 +64,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.density import peel_threshold
+from repro.core.density import peel_threshold, ratio
 from repro.core.distributed import (
     make_sharded_batched_warm_peel, mesh_device_count,
 )
@@ -175,15 +175,19 @@ def _dense_pass(state: PeelState, adj: jax.Array, eps: float) -> PeelState:
     f = failed.astype(jnp.float32)
     a = state.active.astype(jnp.float32)
     af = adj @ f  # failed-neighbor counts (exact integers)
+    # counts reach V-1 = 511, and a default-precision f32 dot may run on the
+    # TPU's MXU with bf16 operands, exact only up to 256. A v5e gave exact
+    # vdots here even so (the Pallas segsum dot did not); HIGHEST makes
+    # exactness a guarantee rather than a property of the lowering
+    hi = jax.lax.Precision.HIGHEST
     removed_directed = (
-        2.0 * jnp.vdot(f, adj @ a) - jnp.vdot(f, af)).astype(jnp.int32)
+        2.0 * jnp.vdot(f, adj @ a, precision=hi)
+        - jnp.vdot(f, af, precision=hi)).astype(jnp.int32)
     n_e_new = state.n_e - removed_directed // 2
     active_new = state.active & ~failed
     deg_new = jnp.where(active_new, state.deg - af.astype(jnp.int32), 0)
     n_v_new = state.n_v - jnp.sum(failed.astype(jnp.int32))
-    rho_new = n_e_new.astype(jnp.float32) / jnp.maximum(n_v_new, 1).astype(
-        jnp.float32)
-    rho_new = jnp.where(n_v_new > 0, rho_new, 0.0)
+    rho_new = jnp.where(n_v_new > 0, ratio(n_e_new, n_v_new), 0.0)
     better = rho_new > state.best_density
     return PeelState(
         deg=deg_new.astype(jnp.int32),
@@ -203,7 +207,7 @@ def _dense_warm_peel_body(adj, deg, n_edges, prev_mask, eps: float):
     active = deg > 0
     n_v = jnp.sum(active.astype(jnp.int32))
     n_e = n_edges.astype(jnp.int32)
-    rho0 = n_e.astype(jnp.float32) / jnp.maximum(n_v, 1).astype(jnp.float32)
+    rho0 = ratio(n_e, n_v)
     state = PeelState(
         deg=deg.astype(jnp.int32),
         active=active,
@@ -216,10 +220,10 @@ def _dense_warm_peel_body(adj, deg, n_edges, prev_mask, eps: float):
     final = jax.lax.while_loop(
         lambda s: s.n_v > 0, lambda s: _dense_pass(s, adj, eps), state)
     pm = prev_mask.astype(jnp.float32)
-    warm_e = jnp.vdot(pm, adj @ pm).astype(jnp.int32) // 2
+    warm_e = jnp.vdot(pm, adj @ pm, precision=jax.lax.Precision.HIGHEST
+                      ).astype(jnp.int32) // 2
     warm_v = jnp.sum(prev_mask.astype(jnp.int32))
-    warm_rho = jnp.where(
-        warm_v > 0, warm_e.astype(jnp.float32) / jnp.maximum(warm_v, 1), 0.0)
+    warm_rho = jnp.where(warm_v > 0, ratio(warm_e, warm_v), 0.0)
     return final, warm_rho
 
 

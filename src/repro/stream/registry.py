@@ -81,7 +81,7 @@ class TenantStats:
     query_steady_ms: float = 0.0
     # kernel-tier dispatch (core/dispatch.py): whether this tenant's degree
     # reductions run through the Pallas segment-sum tier (bit-identical to
-    # the scatter tier; the deploy default follows PALLAS_INTERPRET)
+    # the scatter tier; kernel=None resolves to the scatter tier)
     kernel: bool = False
     # where this tenant's device state lives and how its programs launch:
     # "solo", "sharded", "fused", or "fused+sharded" (one of the four cells
@@ -130,8 +130,7 @@ class GraphRegistry:
         # roster (join/evict = row swap) rather than a compile event
         self.default_fused = bool(fused)
         self.fused_pool = FusedPool()
-        # kernel-tier default: None defers to the deploy default
-        # (core/dispatch.kernel_default — on when PALLAS_INTERPRET=0);
+        # kernel-tier default: None is the scatter tier;
         # per-tenant ``register(kernel=...)`` overrides it
         self.default_kernel = kernel
         self._engines: OrderedDict[str, DeltaEngine] = OrderedDict()
@@ -175,9 +174,7 @@ class GraphRegistry:
         # resolve exactly like DeltaEngine.__init__ will, so the re-register
         # conflict check below compares like with like (sharded engines stay
         # on the scatter tier — ROADMAP follow-up)
-        from repro.core.dispatch import resolve_kernel
-
-        want_kernel = resolve_kernel(
+        want_kernel = bool(
             self.default_kernel if kernel is None else kernel
         ) and not want_sharded
         if name in self._engines:

@@ -26,6 +26,7 @@ response. The synchronous ``density`` API is unchanged.
 """
 from __future__ import annotations
 
+import logging
 import time
 from dataclasses import dataclass, field
 from typing import Any
@@ -37,6 +38,8 @@ from repro.stream.buffer import MIN_CAPACITY
 from repro.stream.delta import DeltaEngine
 from repro.stream.fused import ingest_group, query_group
 from repro.stream.registry import GraphRegistry, placement_of
+
+log = logging.getLogger(__name__)
 
 
 @dataclass
@@ -137,7 +140,7 @@ class StreamService:
         ``placement`` names the resulting cell). ``kernel`` routes the
         tenant's degree reductions through the Pallas segment-sum tier
         (bit-identical results; None defers to the service default, which
-        itself defers to PALLAS_INTERPRET)."""
+        itself resolves to the scatter tier)."""
         with span("service", op="create_tenant", tenant=tenant) as sp:
             try:
                 eng = self.registry.register(tenant, n_nodes, eps=eps,
@@ -298,7 +301,12 @@ class StreamService:
             except Exception:
                 # one tenant's failure must not orphan the whole flush's
                 # tickets: fall back to per-tenant queries so every ticket
-                # gets a response (the failing tenant gets its own error)
+                # gets a response (the failing tenant gets its own error).
+                # The span flag feeds flush_fallback_total, so a batched
+                # program that fails on every flush cannot go unnoticed.
+                log.warning("batched flush of %d tenants failed; answering "
+                            "per tenant", len(engines), exc_info=True)
+                sp.set("flush_fallback", True)
                 results = {}
                 for tenant, eng in engines.items():
                     try:

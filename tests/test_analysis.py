@@ -167,7 +167,7 @@ FIXTURES = [
     ("RPR401", """
         import jax, jax.numpy as jnp
         from jax.sharding import PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
 
         def make(mesh, axes):
             def body(src_l):
@@ -178,7 +178,7 @@ FIXTURES = [
         """, """
         import jax, jax.numpy as jnp
         from jax.sharding import PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
 
         def make(mesh, axes):
             def body(src_l):
@@ -190,7 +190,7 @@ FIXTURES = [
     ("RPR402", """
         import jax, jax.numpy as jnp
         from jax.sharding import PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
 
         def make(mesh):
             def body(src_l):
@@ -200,7 +200,7 @@ FIXTURES = [
         """, """
         import jax, jax.numpy as jnp
         from jax.sharding import PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
 
         def make(mesh):
             def body(src_l):

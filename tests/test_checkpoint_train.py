@@ -142,7 +142,7 @@ def test_peel_with_restarts(tmp_path):
     from repro.graphs.generators import planted_dense
     from repro.core import pbahmani_np
 
-    from repro.utils.compat import make_mesh_auto
+    from repro.utils.mesh import make_mesh_auto
     mesh = make_mesh_auto((1, 1), ("data", "model"))
     g, _, _ = planted_dense(400, 30, seed=2)
     ck = CheckpointManager(str(tmp_path / "peel"), keep=2)

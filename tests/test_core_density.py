@@ -73,3 +73,32 @@ def test_approx_bound_helper():
 def test_known_exact_densities(named_graph):
     rho, mask = exact_densest(named_graph)
     assert rho == pytest.approx(brute_force_densest(named_graph), abs=1e-9)
+
+
+@pytest.mark.parametrize("case", ["counts", "wide_numerator", "exact",
+                                  "halves", "edges"])
+def test_ratio_is_the_ieee_quotient(case):
+    """``ratio`` must equal numpy's correctly rounded f32 quotient bit for
+    bit (the TPU's own f32 division can land one ulp off)."""
+    from repro.core.density import ratio
+
+    rng = np.random.default_rng(7)
+    n = 200_000
+    if case == "counts":
+        num, den = rng.integers(0, 1 << 24, n), rng.integers(0, 1 << 24, n)
+    elif case == "wide_numerator":  # refine load sums pass 2^24
+        num, den = rng.integers(0, 2**31 - 1, n), rng.integers(1, 1 << 24, n)
+    elif case == "exact":           # ties deg == 2 n_e / n_v
+        den = rng.integers(1, 1 << 20, n)
+        num = den * rng.integers(0, 16, n)
+    elif case == "halves":          # quotients on a half-integer
+        den = 2 * rng.integers(1, 1 << 12, n)
+        num = den // 2 * (2 * rng.integers(0, 1 << 11, n) + 1)
+    else:
+        num = np.array([3631, 0, 1, (1 << 24) - 1, 2**31 - 1, 5, 1 << 24])
+        den = np.array([190, 0, (1 << 24) - 1, 1, 3, 0, 3])
+    got = np.asarray(ratio(jnp.asarray(num, jnp.int32),
+                           jnp.asarray(den, jnp.int32)))
+    want = (num.astype(np.float32)
+            / np.maximum(den, 1).astype(np.float32)).astype(np.float32)
+    np.testing.assert_array_equal(got, want)

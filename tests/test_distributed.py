@@ -1,5 +1,7 @@
 """Multi-device tests run in subprocesses with 8 fabricated CPU devices
-(the main pytest process must keep the single real device — see conftest)."""
+(the main pytest process must keep the single real device — see conftest).
+The children are pinned to the CPU (``JAX_PLATFORMS=cpu``): they test
+meshes and checkpoint layouts on fabricated devices, not the chip."""
 import os
 import subprocess
 import sys
@@ -13,6 +15,7 @@ def run_multidev(script: str, devices: int = 8) -> str:
     env = dict(os.environ)
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
     env["PYTHONPATH"] = os.path.join(ROOT, "src")
+    env["JAX_PLATFORMS"] = "cpu"
     out = subprocess.run([sys.executable, "-c", script], env=env,
                          capture_output=True, text=True, timeout=600)
     assert out.returncode == 0, f"STDOUT:\n{out.stdout}\nSTDERR:\n{out.stderr}"
@@ -21,7 +24,7 @@ def run_multidev(script: str, devices: int = 8) -> str:
 
 PREAMBLE = """
 import jax, numpy as np, jax.numpy as jnp
-from repro.utils.compat import make_mesh_auto
+from repro.utils.mesh import make_mesh_auto
 mesh = make_mesh_auto((2, 4), ("data", "model"))
 """
 
@@ -121,15 +124,15 @@ def test_compressed_psum():
 import jax, numpy as np, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 from repro.optim import compressed_psum
-from repro.utils.compat import make_mesh_auto, shard_map_compat
+from repro.utils.mesh import make_mesh_auto
 mesh = make_mesh_auto((8,), ("d",))
 x = jax.random.normal(jax.random.PRNGKey(0), (8, 64, 32))
 
 def body(xl):
     return compressed_psum(xl[0], "d")
 
-out = shard_map_compat(body, mesh=mesh, in_specs=(P("d", None, None),),
-                       out_specs=P(), check_vma=False)(x)
+out = jax.shard_map(body, mesh=mesh, in_specs=(P("d", None, None),),
+                    out_specs=P(), check_vma=False)(x)
 exact = np.asarray(x).sum(axis=0)
 rel = np.abs(np.asarray(out) - exact).max() / np.abs(exact).max()
 assert rel < 0.02, rel   # int8 quantization error bound
@@ -154,7 +157,7 @@ import jax, numpy as np, jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.checkpoint import CheckpointManager
 from repro.launch.train import restore_elastic
-from repro.utils.compat import make_mesh_auto
+from repro.utils.mesh import make_mesh_auto
 mesh = make_mesh_auto((8,), ("data",))
 mgr = CheckpointManager(%r)
 step, st = restore_elastic(
@@ -176,6 +179,7 @@ print("OK")
 """ % str(tmp_path)
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(ROOT, "src")
+    env["JAX_PLATFORMS"] = "cpu"
     out = subprocess.run([sys.executable, "-c", script_1dev], env=env,
                          capture_output=True, text=True, timeout=300)
     assert out.returncode == 0, out.stderr
@@ -185,7 +189,7 @@ def test_vp_segment_sum_matches_reference():
     """Vertex-partitioned aggregation (EXPERIMENTS §Perf #2) == oracle."""
     run_multidev("""
 import jax, jax.numpy as jnp, numpy as np
-from repro.utils.compat import make_mesh_auto
+from repro.utils.mesh import make_mesh_auto
 from repro.kernels import ops as kops
 from repro.kernels.ref import segment_sum_ref
 from repro.graphs.generators import erdos_renyi

@@ -1,5 +1,6 @@
 """Pallas kernel validation: shape/dtype sweeps vs the jnp oracle
-(interpret=True executes the kernel body on CPU)."""
+(off the TPU the platform selects interpret mode, which executes the kernel
+body on CPU)."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -25,6 +26,7 @@ def _random_problem(rng, e, d, v, sorted_=True):
     (2048, 16, 1000),   # many segments
     (513, 7, 100),      # off-by-one edge count
     (100, 200, 50),     # d > E_TILE lanes-worth
+    (5000, 0, 3000),    # many edge tiles and vertex blocks
 ])
 def test_segment_sum_shapes(e, d, v):
     rng = np.random.default_rng(e * 7 + d)
@@ -212,7 +214,8 @@ def _compact_oracle(values: np.ndarray, live: np.ndarray, out_size: int,
     return out
 
 
-@pytest.mark.parametrize("e", [1, 7, P_TILE - 1, P_TILE, P_TILE + 1, 1500])
+@pytest.mark.parametrize("e", [1, 7, P_TILE - 1, P_TILE, P_TILE + 1, 1500,
+                               5000])
 def test_prefix_sum_matches_numpy(e):
     rng = np.random.default_rng(e)
     x = rng.integers(0, 4, e).astype(np.int32)
@@ -235,6 +238,7 @@ def test_prefix_sum_bool_and_extremes():
     (1500, 1024, 0.7),
     (513, 512, 0.3),
     (64, 16, 0.9),     # overflow: survivors > out_size must drop, not wrap
+    (5000, 4096, 0.6),  # many scan tiles
 ])
 def test_stream_compact_matches_scatter(e, out_size, p_live):
     rng = np.random.default_rng(e + out_size)
@@ -273,3 +277,28 @@ def test_stream_compact_all_dead_all_live():
     alive = stream_compact(vals, jnp.ones(300, bool), out_size=512, fill=512)
     np.testing.assert_array_equal(
         np.asarray(alive), np.r_[np.arange(300), np.full(212, 512)])
+
+
+# ---------------------------------------------------------------------------
+# the platform, not a flag, picks interpret mode and the kernel default
+# ---------------------------------------------------------------------------
+def test_platform_selects_interpret_mode():
+    """Off the TPU the kernels run interpreted: the program holds no
+    compiled Pallas call, and kernel=True still answers exactly."""
+    from repro.kernels.segsum import interpret_default, segment_sum_sorted
+
+    assert jax.default_backend() != "tpu"
+    assert interpret_default()
+    lowered = jax.jit(lambda v, s: segment_sum_sorted(
+        v, s, num_segments=8)).lower(jnp.ones(600), jnp.zeros(600, jnp.int32))
+    assert "tpu_custom_call" not in lowered.as_text()
+
+
+@pytest.mark.parametrize("kernel,expected", [(None, False), (False, False),
+                                             (True, True)])
+def test_kernel_knob_resolution(kernel, expected):
+    """kernel=None is the scatter tier on every platform."""
+    from repro.stream import DeltaEngine, GraphRegistry
+
+    assert DeltaEngine(n_nodes=8, kernel=kernel).kernel is expected
+    assert GraphRegistry(kernel=kernel).register("t", 8).kernel is expected

@@ -186,7 +186,7 @@ def test_int8_kv_cache_decode(gqa_cfg):
 def test_zero3_param_specs_cover_all_leaves():
     from repro.models.transformer import param_specs_zero3
     from repro.configs import get_arch
-    from repro.utils.compat import make_mesh_auto
+    from repro.utils.mesh import make_mesh_auto
     mesh = make_mesh_auto((1, 1), ("data", "model"))
     cfg = get_arch("qwen2.5-3b").smoke
     specs = param_specs_zero3(cfg, mesh)

@@ -8,7 +8,9 @@ exact int32 psum, ``DeltaEngine(sharded=True)`` returns the *bit-identical*
 (density, mask, passes) triple of the single-device engine — on a 1-device
 mesh (asserted in-process below) and on forced multi-device CPU meshes
 (asserted in subprocesses, density additionally fp32-checked against the
-numpy oracle, per the acceptance criteria).
+numpy oracle, per the acceptance criteria). Those subprocesses are pinned
+to the CPU (``JAX_PLATFORMS=cpu``): they test fabricated meshes, not the
+chip.
 """
 import os
 import subprocess
@@ -20,7 +22,7 @@ import pytest
 from repro.core.pbahmani import pbahmani_np
 from repro.graphs.graph import Graph
 from repro.stream import DeltaEngine, GraphRegistry, StreamService
-from repro.utils.compat import make_mesh_auto
+from repro.utils.mesh import make_mesh_auto
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -29,6 +31,7 @@ def run_multidev(script: str, devices: int) -> str:
     env = dict(os.environ)
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
     env["PYTHONPATH"] = os.path.join(ROOT, "src")
+    env["JAX_PLATFORMS"] = "cpu"
     out = subprocess.run([sys.executable, "-c", script], env=env,
                          capture_output=True, text=True, timeout=600)
     assert out.returncode == 0, f"STDOUT:\n{out.stdout}\nSTDERR:\n{out.stderr}"
@@ -166,7 +169,7 @@ import numpy as np, jax
 from repro.stream.delta import DeltaEngine
 from repro.core.pbahmani import pbahmani_np
 from repro.graphs.graph import Graph
-from repro.utils.compat import make_mesh_auto
+from repro.utils.mesh import make_mesh_auto
 
 n_dev = len(jax.devices())
 assert n_dev == %d, n_dev

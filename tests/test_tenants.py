@@ -318,6 +318,35 @@ def test_flush_survives_engine_failure():
     assert "exploded" in r_boom.error
 
 
+def test_flush_fallback_is_counted(monkeypatch):
+    """When the batched program itself fails, the flush still answers every
+    ticket per tenant, and ``flush_fallback_total`` counts the fallback."""
+    import repro.stream.service as service_mod
+    from repro.obs.trace import Tracer, set_tracer
+
+    def broken_group(engines):
+        raise RuntimeError("batched program failed")
+
+    tr = Tracer(profiler_bridge=False)
+    prev = set_tracer(tr)
+    try:
+        svc = StreamService(fused=True, coalesce_window_ms=1e9)
+        for name in ("a", "b"):
+            svc.create_tenant(name, n_nodes=20)
+            svc.apply_updates(name, insert=np.array([[0, 1], [1, 2], [0, 2]]))
+        monkeypatch.setattr(service_mod, "query_group", broken_group)
+        tickets = [svc.submit_density("a"), svc.submit_density("b")]
+        assert svc.flush() == 2
+        for t in tickets:
+            r = svc.poll(t)
+            assert r.ok and r.value["density"] == pytest.approx(1.0)
+        fallback = tr.registry.counter("flush_fallback_total", op="flush",
+                                       tenant="-")
+        assert fallback.value == 1
+    finally:
+        set_tracer(prev)
+
+
 def test_group_helpers_accept_unbatched_engines():
     """query_group / ingest_group route plain DeltaEngines through their
     own paths, so mixed fused/unfused registries work (top_k, flush)."""
