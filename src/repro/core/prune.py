@@ -82,6 +82,7 @@ from repro.core.kcore import CoreState, _level_fixpoint
 from repro.core.pbahmani import PeelState, pbahmani_pass
 from repro.graphs.graph import Graph
 from repro.kernels.compact import stream_compact
+from repro.obs.trace import span, tally
 from repro.utils.num import next_pow2
 
 MIN_BUCKET_V = 64     # smallest compacted vertex space (pow-2 buckets above)
@@ -869,7 +870,9 @@ def merge_pruned_peel(
     density = np.float32(d_b)
     passes = int(passes_b)
     if density > pd.best_d1:  # strict >: earliest best wins, as unpruned
-        mask_b = np.asarray(mask_b)
+        if not isinstance(mask_b, np.ndarray):
+            mask_b = np.asarray(mask_b)
+            tally("d2h_bytes", mask_b.nbytes)
         mask = pd.a1 & mask_b[np.minimum(pd.perm, pd.plan.bucket_v - 1)]
     else:
         mask = pd.a1 if pd.better1 else pd.active0
@@ -890,6 +893,11 @@ def pruned_peel_host(
     host merge. ``u, v`` are undirected host slot arrays (sentinel-padded),
     ``deg`` the exact int32 degree array (len == vertex space == sentinel).
 
+    ``deg`` may be the device-resident array: it is pulled inside the
+    ``prune_prepare`` span. The three halves run in the spans
+    ``prune_prepare``, ``prune_peel`` (which times the enqueue of the
+    bucket peel) and ``prune_merge`` (whose first read waits for it).
+
     Returns (density, mask, passes, observed_handoff, plan) — ``plan`` may
     have grown if the observed survivor set missed the given buckets, or
     *shrunk* if the graph contracted past the hysteresis (the host sees the
@@ -905,32 +913,50 @@ def pruned_peel_host(
     bucket COO is emitted dst-sorted either way); the sharded path stays
     on per-shard scatter — lanes are mesh-partitioned, not band-local.
     """
-    prep = prepare_pruned_peel(u, v, deg, n_edges, eps, plan)
+    with span("prune_prepare") as sp:
+        if not isinstance(deg, np.ndarray):
+            deg = np.asarray(deg)
+            tally("d2h_bytes", deg.nbytes)
+        prep = prepare_pruned_peel(u, v, deg, n_edges, eps, plan)
+        if prep is None:
+            sp.set("fell_back", True)
     if prep is None or isinstance(prep, tuple):
         return prep
     pd = prep
     plan = pd.plan
-    if mesh is None:
-        d_b, mask_b, passes_b = _bucket_peel_jit(
-            jnp.asarray(pd.b_src), jnp.asarray(pd.b_dst),
-            jnp.asarray(pd.n_v1, jnp.int32), jnp.asarray(pd.n_e1, jnp.int32),
-            jnp.asarray(pd.best_d1, jnp.float32), jnp.asarray(1, jnp.int32),
-            float(eps), *plan.buckets, kernel,
-        )
-    else:
-        if plan.bucket_e % mesh_device_count(mesh):
-            # the candidate set is smaller than one lane per device can
-            # express — pruning cannot pay off on this mesh; fall back to
-            # the (always shardable) full-width path instead of raising
-            return None
-        run = _make_sharded_bucket_peel(mesh, float(eps), *plan.buckets)
-        sh = edge_sharding(mesh)
-        d_b, mask_b, passes_b = run(
-            jax.device_put(pd.b_src, sh), jax.device_put(pd.b_dst, sh),
-            jnp.asarray(pd.n_v1, jnp.int32), jnp.asarray(pd.n_e1, jnp.int32),
-            jnp.asarray(pd.best_d1, jnp.float32), jnp.asarray(1, jnp.int32),
-        )
-    return merge_pruned_peel(pd, d_b, mask_b, passes_b)
+    with span("prune_peel") as sp:
+        # one-element scalars ride along uncounted
+        if mesh is None:
+            tally("h2d_bytes", pd.b_src.nbytes + pd.b_dst.nbytes)
+            d_b, mask_b, passes_b = _bucket_peel_jit(
+                jnp.asarray(pd.b_src), jnp.asarray(pd.b_dst),
+                jnp.asarray(pd.n_v1, jnp.int32),
+                jnp.asarray(pd.n_e1, jnp.int32),
+                jnp.asarray(pd.best_d1, jnp.float32),
+                jnp.asarray(1, jnp.int32), float(eps), *plan.buckets, kernel,
+            )
+        else:
+            if plan.bucket_e % mesh_device_count(mesh):
+                # the candidate set is smaller than one lane per device can
+                # express — pruning cannot pay off on this mesh; fall back
+                # to the (always shardable) full-width path instead of
+                # raising
+                sp.set("fell_back", True)
+                return None
+            run = _make_sharded_bucket_peel(mesh, float(eps), *plan.buckets)
+            sh = edge_sharding(mesh)
+            tally("h2d_bytes", pd.b_src.nbytes + pd.b_dst.nbytes)
+            d_b, mask_b, passes_b = run(
+                jax.device_put(pd.b_src, sh), jax.device_put(pd.b_dst, sh),
+                jnp.asarray(pd.n_v1, jnp.int32),
+                jnp.asarray(pd.n_e1, jnp.int32),
+                jnp.asarray(pd.best_d1, jnp.float32),
+                jnp.asarray(1, jnp.int32),
+            )
+    with span("prune_merge") as sp:
+        out = merge_pruned_peel(pd, d_b, mask_b, passes_b)
+        sp.set("passes", out[2])
+    return out
 
 
 def plan_for_graph(

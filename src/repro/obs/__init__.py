@@ -1,8 +1,8 @@
 """repro.obs — unified observability: spans, exact-rank metrics, recompile
 audit, Prometheus/JSON export, and the mesh-wide telemetry plane
-(cross-process collector, scrape endpoint, OTLP export, SLO burn-rate
-alerts). Host-side only by construction: nothing here dispatches to jax,
-so enabling tracing — or running a live scrape server and collector push —
+(cross-process collector, scrape endpoint, SLO burn-rate alerts).
+Host-side only by construction: nothing here dispatches to jax, so
+enabling tracing — or running a live scrape server and collector push —
 cannot change results or add steady-state recompiles (asserted in
 tests/test_obs.py and tests/test_telemetry.py)."""
 from repro.obs.audit import AUDITOR, AuditRecord, RecompileAuditor
@@ -16,7 +16,6 @@ from repro.obs.export import (
     unescape_label_value,
     write_json,
 )
-from repro.obs.otlp import OtlpExporter, otel_available
 from repro.obs.scrape import MetricsServer, serve_metrics
 from repro.obs.slo import BurnRatePolicy, SloMonitor, burn_exceeds
 from repro.obs.metrics import (
@@ -36,6 +35,7 @@ from repro.obs.trace import (
     read_jsonl,
     set_tracer,
     span,
+    tally,
 )
 
 __all__ = [
@@ -45,9 +45,8 @@ __all__ = [
     "Counter", "Gauge", "Histogram", "MetricsRegistry",
     "DEFAULT_LATENCY_BOUNDS_MS",
     "NOOP_SPAN", "Span", "SpanRecord", "Tracer",
-    "configure", "get_tracer", "set_tracer", "span", "read_jsonl",
+    "configure", "get_tracer", "set_tracer", "span", "tally", "read_jsonl",
     "Collector", "CollectorServer", "push_snapshot", "write_spool",
     "MetricsServer", "serve_metrics",
     "BurnRatePolicy", "SloMonitor", "burn_exceeds",
-    "OtlpExporter", "otel_available",
 ]

@@ -16,6 +16,15 @@ per-tenant latency histogram — split into ``<name>_ms`` (steady) versus
 ``compiled=True``, which is what un-conflates compile time from
 steady-state latency (ISSUE 6 satellite).
 
+Children carry the request: a span opened with no labels of its own takes
+its parent's, and every record carries ``trace_id``, the ``span_id`` of its
+root span, so the phases of one request share an identifier. ``t_mono_ns``
+is ``time.perf_counter_ns()`` at enter, the clock a benchmark window is
+measured on, so a record can be joined with a profiler trace through one
+anchor. :func:`tally` adds to an attribute of the innermost open span: the
+engines count host<->device transfer bytes (``h2d_bytes``/``d2h_bytes``)
+where the copies happen, and the span that was open owns them.
+
 Two hard properties:
 
   * **host-side only** — a span never calls into jax except the optional
@@ -44,6 +53,8 @@ ATTR_COUNTERS = {
     "refine_rounds": "refine_rounds_total",
     "n_inserted": "edges_inserted_total",
     "n_deleted": "edges_deleted_total",
+    "h2d_bytes": "h2d_bytes_total",
+    "d2h_bytes": "d2h_bytes_total",
 }
 ATTR_GAUGES = {
     "certified_gap": "certified_gap",
@@ -69,19 +80,23 @@ class SpanRecord:
     t_start: float          # time.time() epoch seconds (JSONL-friendly)
     duration_ms: float
     attrs: dict = field(default_factory=dict)
+    trace_id: int | None = None  # span_id of the root span of this request
+    t_mono_ns: int = 0      # time.perf_counter_ns() at enter
 
     def to_json(self) -> dict:
         return {"span_id": self.span_id, "parent_id": self.parent_id,
                 "depth": self.depth, "name": self.name, "labels": self.labels,
                 "t_start": self.t_start, "duration_ms": self.duration_ms,
-                "attrs": self.attrs}
+                "attrs": self.attrs, "trace_id": self.trace_id,
+                "t_mono_ns": self.t_mono_ns}
 
     @classmethod
     def from_json(cls, d: dict) -> "SpanRecord":
         return cls(span_id=d["span_id"], parent_id=d["parent_id"],
                    depth=d["depth"], name=d["name"], labels=d["labels"],
                    t_start=d["t_start"], duration_ms=d["duration_ms"],
-                   attrs=d.get("attrs", {}))
+                   attrs=d.get("attrs", {}), trace_id=d.get("trace_id"),
+                   t_mono_ns=d.get("t_mono_ns", 0))
 
 
 class _NoopSpan:
@@ -107,7 +122,7 @@ class Span:
     """Live span; use via ``with tracer.span(...) as sp``."""
 
     __slots__ = ("tracer", "name", "labels", "attrs", "span_id", "parent_id",
-                 "depth", "_t0", "_wall", "duration_ms", "_ann")
+                 "depth", "trace_id", "_t0", "_wall", "duration_ms", "_ann")
 
     def __init__(self, tracer: "Tracer", name: str, labels: dict):
         self.tracer = tracer
@@ -117,6 +132,7 @@ class Span:
         self.span_id = next(tracer._ids)
         self.parent_id = None
         self.depth = 0
+        self.trace_id = self.span_id
         self.duration_ms = 0.0
         self._ann = None
 
@@ -128,13 +144,17 @@ class Span:
     def elapsed_ms(self) -> float:
         """Wall time so far (span still open) — what the service uses for
         per-request latency without a second clock source."""
-        return (time.perf_counter() - self._t0) * 1e3
+        return (time.perf_counter_ns() - self._t0) * 1e-6
 
     def __enter__(self) -> "Span":
         stack = self.tracer._stack
         if stack:
-            self.parent_id = stack[-1].span_id
+            parent = stack[-1]
+            self.parent_id = parent.span_id
             self.depth = len(stack)
+            self.trace_id = parent.trace_id
+            if not self.labels:
+                self.labels = parent.labels
         stack.append(self)
         if self.tracer.profiler_bridge:
             # imported here, not at module top: a process that only
@@ -144,11 +164,11 @@ class Span:
             self._ann = TraceAnnotation(f"obs:{self.name}")
             self._ann.__enter__()
         self._wall = time.time()
-        self._t0 = time.perf_counter()
+        self._t0 = time.perf_counter_ns()
         return self
 
     def __exit__(self, *exc) -> bool:
-        self.duration_ms = (time.perf_counter() - self._t0) * 1e3
+        self.duration_ms = (time.perf_counter_ns() - self._t0) * 1e-6
         if self._ann is not None:
             self._ann.__exit__(*exc)
         stack = self.tracer._stack
@@ -188,6 +208,14 @@ class Tracer:
         if not self.enabled:         # the one-branch disabled fast path
             return NOOP_SPAN
         return Span(self, name, labels)
+
+    def tally(self, key: str, n: int) -> None:
+        """Add ``n`` to attribute ``key`` of the innermost open span; with
+        no span open (always, on a disabled tracer) nothing happens."""
+        stack = self._stack
+        if stack:
+            attrs = stack[-1].attrs
+            attrs[key] = attrs.get(key, 0) + int(n)
 
     def ring(self) -> list[SpanRecord]:
         return list(self._ring)
@@ -231,7 +259,8 @@ class Tracer:
         rec = SpanRecord(span_id=sp.span_id, parent_id=sp.parent_id,
                          depth=sp.depth, name=sp.name, labels=sp.labels,
                          t_start=sp._wall, duration_ms=sp.duration_ms,
-                         attrs=dict(sp.attrs))
+                         attrs=dict(sp.attrs), trace_id=sp.trace_id,
+                         t_mono_ns=sp._t0)
         self._ring.append(rec)
         if self._jsonl_path is not None:
             if self._jsonl_file is None:
@@ -301,6 +330,11 @@ def span(name: str, **labels):
     return _TRACER.span(name, **labels)
 
 
-__all__ = ["Span", "SpanRecord", "Tracer", "NOOP_SPAN", "span", "get_tracer",
-           "set_tracer", "configure", "read_jsonl", "ATTR_COUNTERS",
-           "ATTR_GAUGES", "ATTR_FLAG_COUNTERS"]
+def tally(key: str, n: int) -> None:
+    """Convenience: :meth:`Tracer.tally` on the process-default tracer."""
+    _TRACER.tally(key, n)
+
+
+__all__ = ["Span", "SpanRecord", "Tracer", "NOOP_SPAN", "span", "tally",
+           "get_tracer", "set_tracer", "configure", "read_jsonl",
+           "ATTR_COUNTERS", "ATTR_GAUGES", "ATTR_FLAG_COUNTERS"]

@@ -36,6 +36,7 @@ from __future__ import annotations
 import numpy as np
 
 from repro.graphs.graph import Graph
+from repro.obs.trace import span
 from repro.utils.num import next_pow2
 
 MIN_CAPACITY = 256  # matches Graph.from_edges pad_multiple: shared jit shapes
@@ -117,35 +118,42 @@ class EdgeBuffer:
         ``compact_threshold`` the buffer compacts itself before returning
         (``generation`` bumps, so callers holding device state must resync —
         the returned slot indices refer to the pre-compaction layout)."""
+        # the loops touch only the dict; the slot arrays are written once
+        # per batch. Pairs cross as tuples of Python ints zipped from two
+        # flat ``tolist`` columns: no numpy scalars, and no row lists for
+        # the collector to carry
+        table = self._slot
         deleted, del_slots = [], []
         if delete is not None:
-            for u, v in self._canonicalize(delete):
-                slot = self._slot.pop((int(u), int(v)), None)
-                if slot is None:
-                    continue
-                self._u[slot] = self.sentinel
-                self._v[slot] = self.sentinel
-                self._holes.append(slot)
-                deleted.append((int(u), int(v)))
-                del_slots.append(slot)
+            pop = table.pop
+            dl = self._canonicalize(delete)
+            for key in zip(dl[:, 0].tolist(), dl[:, 1].tolist()):
+                slot = pop(key, None)
+                if slot is not None:
+                    deleted.append(key)
+                    del_slots.append(slot)
+            idx = np.asarray(del_slots, dtype=np.int64)
+            self._u[idx] = self.sentinel
+            self._v[idx] = self.sentinel
+            self._holes.extend(del_slots)
         inserted, ins_slots = [], []
         if insert is not None:
             ins = self._canonicalize(insert)
             if ins.size:
-                ins = np.unique(ins, axis=0)
-            new = [
-                (int(u), int(v)) for u, v in ins if (int(u), int(v)) not in self._slot
-            ]
+                # unique pairs in (u, v) order, as one int64 key each
+                key = np.unique(ins[:, 0] * self.n_nodes + ins[:, 1])
+                ins = np.stack([key // self.n_nodes, key % self.n_nodes], 1)
+            inserted = [k for k in zip(ins[:, 0].tolist(), ins[:, 1].tolist())
+                        if k not in table]
             # grow once, up front, if the effective batch cannot fit
-            if len(self._slot) + len(new) > self.capacity:
-                self._grow(next_pow2(len(self._slot) + len(new)))
-            for key in new:
-                slot = self._holes.pop() if self._holes else self._fresh.pop()
-                self._slot[key] = slot
-                self._u[slot] = key[0]
-                self._v[slot] = key[1]
-                inserted.append(key)
-                ins_slots.append(slot)
+            if len(table) + len(inserted) > self.capacity:
+                self._grow(next_pow2(len(table) + len(inserted)))
+            ins_slots = self._take_slots(len(inserted))
+            table.update(zip(inserted, ins_slots))
+            pairs = np.asarray(inserted, dtype=np.int32).reshape(-1, 2)
+            idx = np.asarray(ins_slots, dtype=np.int64)
+            self._u[idx] = pairs[:, 0]
+            self._v[idx] = pairs[:, 1]
         self._version += 1
         if (self.compact_threshold is not None
                 and len(self._holes) > self.compact_threshold * self.capacity):
@@ -156,6 +164,19 @@ class EdgeBuffer:
             np.asarray(deleted, dtype=np.int32).reshape(-1, 2),
             np.asarray(del_slots, dtype=np.int32),
         )
+
+    def _take_slots(self, k: int) -> list[int]:
+        """``k`` free slots: the newest holes first, then the lowest fresh
+        slots (the order of popping each list from its end)."""
+        holes, fresh = self._holes, self._fresh
+        h = min(k, len(holes))
+        taken = holes[len(holes) - h:][::-1]
+        del holes[len(holes) - h:]
+        f = k - h
+        if f:
+            taken += fresh[len(fresh) - f:][::-1]
+            del fresh[len(fresh) - f:]
+        return taken
 
     def _grow(self, new_capacity: int) -> None:
         new_capacity = max(next_pow2(new_capacity), 2 * self.capacity)
@@ -186,29 +207,35 @@ class EdgeBuffer:
         also drop to ``shrink_target()`` when the hysteresis allows. Called
         by the delta engine's epoch refresh; O(n_edges), amortized away by
         the epoch. Returns True when capacity changed."""
-        new_capacity = self.capacity
-        if shrink:
-            target = self.shrink_target()
-            if target is not None:
-                new_capacity = target
-        pairs = sorted(self._slot)
-        if new_capacity != self.capacity:
-            self._u = np.full(new_capacity, self.sentinel, dtype=np.int32)
-            self._v = np.full(new_capacity, self.sentinel, dtype=np.int32)
-        else:
-            self._u.fill(self.sentinel)
-            self._v.fill(self.sentinel)
-        shrunk = new_capacity != self.capacity
-        self.capacity = new_capacity
-        self._slot = {}
-        for i, (u, v) in enumerate(pairs):
-            self._slot[(u, v)] = i
-            self._u[i] = u
-            self._v[i] = v
-        self._fresh = list(range(self.capacity - 1, len(pairs) - 1, -1))
-        self._holes = []
-        self.generation += 1
-        self._version += 1
+        with span("buffer_compact") as sp:
+            new_capacity = self.capacity
+            if shrink:
+                target = self.shrink_target()
+                if target is not None:
+                    new_capacity = target
+            # the live pairs are the slots off the sentinel; sorted by
+            # (u, v) they fill the dense prefix
+            live = np.flatnonzero(self._u != self.sentinel)
+            u, v = self._u[live], self._v[live]
+            order = np.lexsort((v, u))
+            u, v = u[order], v[order]
+            if new_capacity != self.capacity:
+                self._u = np.full(new_capacity, self.sentinel, np.int32)
+                self._v = np.full(new_capacity, self.sentinel, np.int32)
+            else:
+                self._u.fill(self.sentinel)
+                self._v.fill(self.sentinel)
+            shrunk = new_capacity != self.capacity
+            self.capacity = new_capacity
+            n = u.size
+            self._u[:n] = u
+            self._v[:n] = v
+            self._slot = dict(zip(zip(u.tolist(), v.tolist()), range(n)))
+            self._fresh = list(range(self.capacity - 1, n - 1, -1))
+            self._holes = []
+            self.generation += 1
+            self._version += 1
+            sp.set("n_edges", n).set("shrunk", shrunk)
         return shrunk
 
     # -- views --------------------------------------------------------------

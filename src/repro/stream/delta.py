@@ -86,7 +86,7 @@ from repro.core.prune import (
     build_plan, make_sharded_plan, pruned_peel_host,
 )
 from repro.obs.audit import AUDITOR
-from repro.obs.trace import span
+from repro.obs.trace import span, tally
 from repro.refine.certify import GapCertificate, make_certificate
 from repro.refine.engine import DEFAULT_TARGET_GAP, refine_resident
 from repro.refine.loads import REFINE_JITS
@@ -680,49 +680,62 @@ class DeltaEngine:
         mode uploads the buffer's dst-sorted snapshot instead (the Pallas
         tier's band-skip precondition) and caches its lane permutation so
         later batches patch the sorted layout in O(batch)."""
-        if self.kernel:
-            assert_exact_envelope(2 * self.buffer.capacity,
-                                  self.node_capacity)
-            src, dst, deg, lane_perm = self.buffer.dst_sorted_state(
-                self.node_capacity)
-            self._lane_perm = lane_perm
-            self._src = jnp.asarray(src)
-            self._dst = jnp.asarray(dst)
-            self._deg = jnp.asarray(deg)
+        with span("engine_resync", tenant=self.tenant, engine=self.kind):
+            if self.kernel:
+                assert_exact_envelope(2 * self.buffer.capacity,
+                                      self.node_capacity)
+                src, dst, deg, lane_perm = self.buffer.dst_sorted_state(
+                    self.node_capacity)
+                self._lane_perm = lane_perm
+            else:
+                src, dst, deg = self.buffer.resident_state(self.node_capacity)
+            tally("h2d_bytes", src.nbytes + dst.nbytes + deg.nbytes)
+            if self.mesh is not None:
+                prev = np.asarray(self._prev_mask)
+                tally("d2h_bytes", prev.nbytes)
+                tally("h2d_bytes", prev.nbytes)
+                self._src, self._dst, self._deg, self._prev_mask = (
+                    _make_sharded_resync(self.mesh)(src, dst, deg, prev))
+            else:
+                self._src = jnp.asarray(src)
+                self._dst = jnp.asarray(dst)
+                self._deg = jnp.asarray(deg)
             self._generation = self.buffer.generation
-            return
-        src, dst, deg = self.buffer.resident_state(self.node_capacity)
-        if self.mesh is not None:
-            self._src, self._dst, self._deg, self._prev_mask = (
-                _make_sharded_resync(self.mesh)(
-                    src, dst, deg, np.asarray(self._prev_mask)))
-        else:
-            self._src = jnp.asarray(src)
-            self._dst = jnp.asarray(dst)
-            self._deg = jnp.asarray(deg)
-        self._generation = self.buffer.generation
 
-    def _check_endpoints(self, edges) -> None:
+    def _check_endpoints(self, edges) -> int:
+        """Raise on an out-of-range endpoint; returns the number of pairs."""
+        if edges is None:
+            return 0
         e = np.asarray(edges, dtype=np.int64).reshape(-1, 2)
         if e.size and (e.min() < 0 or e.max() >= self.n_nodes):
             raise ValueError(
                 f"edge endpoint out of range [0, {self.n_nodes}): "
                 f"min={e.min()} max={e.max()}"
             )
+        return e.shape[0]
 
     # -- ingest -------------------------------------------------------------
     def apply_updates(self, insert=None, delete=None) -> UpdateStats:
-        with span("ingest", tenant=self.tenant, engine=self.kind) as sp:
+        labels = {"tenant": self.tenant, "engine": self.kind}
+        with span("ingest", **labels) as sp:
             AUDITOR.sync()  # foreign cache growth is not this batch's fault
-            if insert is not None:
-                self._check_endpoints(insert)
-            if delete is not None:
-                self._check_endpoints(delete)
             if self._generation < 0:
                 self._resync_device()
 
-            gen_before = self.buffer.generation
-            ins, ins_slots, dele, del_slots = self.buffer.apply(insert, delete)
+            with span("buffer_apply", **labels) as bsp:
+                n_ins = self._check_endpoints(insert)
+                n_del = self._check_endpoints(delete)
+                gen_before = self.buffer.generation
+                ins, ins_slots, dele, del_slots = self.buffer.apply(
+                    insert, delete)
+                # the counts ride here, not on ``ingest``: a count on both
+                # would feed its counter twice
+                bsp.set("n_inserted", int(ins.shape[0]))
+                bsp.set("n_deleted", int(dele.shape[0]))
+                # offered pairs with no effect: duplicates, self-loops and
+                # live edges; deletes of absent edges
+                bsp.set("n_dup_inserts", n_ins - int(ins.shape[0]))
+                bsp.set("n_absent_deletes", n_del - int(dele.shape[0]))
             regrew = self.buffer.generation != gen_before
 
             if regrew:
@@ -734,12 +747,15 @@ class DeltaEngine:
                 self._plan = None
             else:
                 # pow-2 batch pad; sharded engines also need the batch
-                # divisible into per-device histogram slices (pow-2 shards)
-                row = _build_batch_row(
-                    ins, ins_slots, dele, del_slots, self.buffer.capacity,
-                    self.sentinel, b_floor=max(MIN_BATCH, self.n_shards))
-                b = row[0].shape[0]
-                self._dispatch_batch(*row)
+                # divisible into per-device histogram slices (pow-2 shards).
+                # The span times the enqueue; the scatter runs on after it
+                with span("ingest_dispatch", **labels) as dsp:
+                    row = _build_batch_row(
+                        ins, ins_slots, dele, del_slots, self.buffer.capacity,
+                        self.sentinel, b_floor=max(MIN_BATCH, self.n_shards))
+                    b = row[0].shape[0]
+                    self._dispatch_batch(*row)
+                    dsp.set("batch_width", b)
                 self.metrics.shape_buckets.add((2 * self.buffer.capacity, b))
 
             # staleness ages faster on delete-heavy batches: tombstone holes
@@ -764,8 +780,6 @@ class DeltaEngine:
             # _audit_shape already reflects
             shape = self._audit_shape() + (("resync",) if regrew else (b,))
             compiled = AUDITOR.record(self.tenant, "ingest", shape)
-            sp.set("n_inserted", int(ins.shape[0]))
-            sp.set("n_deleted", int(dele.shape[0]))
             sp.set("compiled", compiled)
             sp.set("kernel", self.kernel)
             ms = sp.elapsed_ms
@@ -798,6 +812,8 @@ class DeltaEngine:
             p1 = np.where(real, self._lane_perm[sc], 2 * cap).astype(np.int32)
             p2 = np.where(real, self._lane_perm[sc + cap],
                           2 * cap).astype(np.int32)
+            tally("h2d_bytes", sum(a.nbytes for a in (p1, p2, su, sv, du,
+                                                      dv, w)))
             self._src, self._dst, self._deg = _apply_batch_sorted_jit(
                 self._src, self._dst, self._deg,
                 jnp.asarray(p1), jnp.asarray(p2), jnp.asarray(su),
@@ -805,6 +821,7 @@ class DeltaEngine:
                 jnp.asarray(w), self.node_capacity,
             )
             return
+        tally("h2d_bytes", sum(a.nbytes for a in (slots, su, sv, du, dv, w)))
         if self.mesh is not None:
             apply_fn = _make_sharded_apply(self.mesh, self.node_capacity)
             self._src, self._dst, self._deg = apply_fn(
@@ -827,25 +844,27 @@ class DeltaEngine:
         edges, so the bound stays sound after deletions); the last observed
         handoff sizes the buckets with slack, so steady-state epochs keep
         reusing one compiled executable (``bucket_reuses``)."""
-        if self.mesh is not None:
-            rho_lb, k, _, n_cand, ne_cand = make_sharded_plan(
-                self.mesh, self.node_capacity)(
-                self._src, self._dst, self._prev_mask,
-                jnp.asarray(self.buffer.n_edges, jnp.int32),
+        with span("prune_plan", tenant=self.tenant, engine=self.kind) as sp:
+            if self.mesh is not None:
+                rho_lb, k, _, n_cand, ne_cand = make_sharded_plan(
+                    self.mesh, self.node_capacity)(
+                    self._src, self._dst, self._prev_mask,
+                    jnp.asarray(self.buffer.n_edges, jnp.int32),
+                )
+            else:
+                rho_lb, k, _, n_cand, ne_cand = _plan_jit(
+                    self._src, self._dst, self._prev_mask,
+                    jnp.asarray(self.buffer.n_edges, jnp.int32),
+                    self.node_capacity, self.kernel,
+                )
+            new = build_plan(
+                float(rho_lb), int(k), int(n_cand), int(ne_cand),
+                node_width=self.node_capacity,
+                lane_width=2 * self.buffer.capacity,
+                observed=self._last_handoff,
+                n_vertices=self.n_nodes,
             )
-        else:
-            rho_lb, k, _, n_cand, ne_cand = _plan_jit(
-                self._src, self._dst, self._prev_mask,
-                jnp.asarray(self.buffer.n_edges, jnp.int32),
-                self.node_capacity, self.kernel,
-            )
-        new = build_plan(
-            float(rho_lb), int(k), int(n_cand), int(ne_cand),
-            node_width=self.node_capacity,
-            lane_width=2 * self.buffer.capacity,
-            observed=self._last_handoff,
-            n_vertices=self.n_nodes,
-        )
+            sp.set("candidate_fraction", new.candidate_fraction)
         if self._plan is not None and new.buckets == self._plan.buckets:
             self.metrics.bucket_reuses += 1
         self._plan = new
@@ -863,7 +882,7 @@ class DeltaEngine:
         the full-width path; counted as a prune fallback)."""
         u, v = self.buffer.host_view()
         res = pruned_peel_host(
-            u, v, np.asarray(self._deg),
+            u, v, self._deg,
             self.buffer.n_edges, self.eps, self._plan, mesh=self.mesh,
             kernel=self.kernel,
         )
@@ -891,6 +910,7 @@ class DeltaEngine:
             self._plan = plan
             self.metrics.prune_bucket_v = plan.bucket_v
             self.metrics.prune_bucket_e = plan.bucket_e
+        tally("h2d_bytes", mask.nbytes)
         if self.mesh is not None:
             self._prev_mask = _make_sharded_mask_sync(self.mesh)(
                 jnp.asarray(mask))
@@ -941,17 +961,24 @@ class DeltaEngine:
                 density, mask, passes = out
                 pruned_flag = True
             else:
-                final = self._cold_full_peel()
-                self._prev_mask = final.best_mask
-                density = float(final.best_density)
-                mask = np.asarray(final.best_mask)[: self.n_nodes]
-                passes = int(final.passes)
+                with span("peel_full", tenant=self.tenant,
+                          engine=self.kind) as psp:
+                    final = self._cold_full_peel()
+                    self._prev_mask = final.best_mask
+                    density = float(final.best_density)
+                    full = np.asarray(final.best_mask)
+                    tally("d2h_bytes", full.nbytes)
+                    mask = full[: self.n_nodes]
+                    passes = int(final.passes)
+                    psp.set("passes", passes)
                 pruned_flag = False
             buckets = (self._plan.buckets
                        if pruned_flag and self._plan is not None else None)
             compiled = AUDITOR.record(
                 self.tenant, "refresh", self._audit_shape() + (buckets,))
-            sp.set("passes", passes).set("density", density)
+            # the passes ride on the peel's own span (peel_full or
+            # prune_merge), so the counter sees each answer once
+            sp.set("density", density)
             sp.set("path", "pruned" if pruned_flag else "warm")
             sp.set("compiled", compiled)
             sp.set("kernel", self.kernel)
@@ -1010,36 +1037,43 @@ class DeltaEngine:
                 buckets = self._plan.buckets
                 sp.set("candidate_fraction", self.metrics.candidate_fraction)
             else:
-                if self.mesh is not None:
-                    final, warm_rho = make_sharded_warm_peel(
-                        self.mesh, self.node_capacity, self.eps)(
-                        self._src, self._dst, self._deg,
-                        jnp.asarray(self.buffer.n_edges, jnp.int32),
-                        self._prev_mask)
-                else:
-                    final, warm_rho = _warm_peel_jit(
-                        self._src, self._dst, self._deg,
-                        jnp.asarray(self.buffer.n_edges, jnp.int32),
-                        self._prev_mask, self.node_capacity, self.eps,
-                        self.kernel,
-                    )
-                density = float(final.best_density)
-                warm_rho = float(warm_rho)
-                mask = np.asarray(final.best_mask)[: self.n_nodes]
-                passes = int(final.passes)
-                if warm_rho > density:
-                    warm_density = warm_rho
-                    warm_mask = np.asarray(self._prev_mask)[: self.n_nodes]
-                    # keep the stronger candidate as next query's warm seed
-                else:
-                    warm_density = density
-                    warm_mask = mask.copy()
-                    self._prev_mask = final.best_mask
+                with span("peel_full", tenant=self.tenant,
+                          engine=self.kind) as psp:
+                    if self.mesh is not None:
+                        final, warm_rho = make_sharded_warm_peel(
+                            self.mesh, self.node_capacity, self.eps)(
+                            self._src, self._dst, self._deg,
+                            jnp.asarray(self.buffer.n_edges, jnp.int32),
+                            self._prev_mask)
+                    else:
+                        final, warm_rho = _warm_peel_jit(
+                            self._src, self._dst, self._deg,
+                            jnp.asarray(self.buffer.n_edges, jnp.int32),
+                            self._prev_mask, self.node_capacity, self.eps,
+                            self.kernel,
+                        )
+                    density = float(final.best_density)
+                    warm_rho = float(warm_rho)
+                    full = np.asarray(final.best_mask)
+                    tally("d2h_bytes", full.nbytes)
+                    mask = full[: self.n_nodes]
+                    passes = int(final.passes)
+                    psp.set("passes", passes)
+                    if warm_rho > density:
+                        warm_density = warm_rho
+                        prev = np.asarray(self._prev_mask)
+                        tally("d2h_bytes", prev.nbytes)
+                        warm_mask = prev[: self.n_nodes]
+                        # keep the stronger candidate as next query's seed
+                    else:
+                        warm_density = density
+                        warm_mask = mask.copy()
+                        self._prev_mask = final.best_mask
                 pruned_flag = False
                 buckets = None
             compiled = AUDITOR.record(
                 self.tenant, "query", self._audit_shape() + (buckets,))
-            sp.set("passes", passes).set("density", density)
+            sp.set("density", density)
             sp.set("path", "pruned" if pruned_flag else "warm")
             sp.set("compiled", compiled)
             sp.set("kernel", self.kernel)

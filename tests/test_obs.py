@@ -18,6 +18,7 @@ from repro.obs import (
     Histogram,
     MetricsRegistry,
     RecompileAuditor,
+    SpanRecord,
     Tracer,
     prometheus_text,
     read_jsonl,
@@ -266,10 +267,27 @@ def _drive(eng, rng, n, refine_every=0):
     return results
 
 
+# the phase spans each kind of engine opens under its ingest and queries
+PHASE_SPANS = {
+    "warm": {"ingest", "buffer_apply", "ingest_dispatch", "engine_resync",
+             "query", "peel_full"},
+    "pruned": {"ingest", "buffer_apply", "ingest_dispatch", "engine_resync",
+               "query", "prune_plan", "prune_prepare", "prune_peel",
+               "prune_merge"},
+    "refined": {"ingest", "buffer_apply", "ingest_dispatch", "query",
+                "refine", "prune_prepare", "prune_peel", "prune_merge"},
+}
+
+
+@pytest.mark.parametrize("tracing", [True, False],
+                         ids=["tracer_on", "tracer_off"])
 @pytest.mark.parametrize("kind", ["warm", "pruned", "refined"])
-def test_engine_oracle_parity_with_tracing_enabled(fresh_tracer, kind):
+def test_engine_oracle_parity_with_tracing_enabled(fresh_tracer, kind,
+                                                    tracing):
     """Bit-identity against the numpy oracle with spans recording on every
-    op, and zero audited steady-state recompiles over the steady window."""
+    op (phase spans included), and with the tracer off; zero audited
+    steady-state recompiles over the steady window either way."""
+    fresh_tracer.enabled = tracing
     rng = np.random.default_rng(hash(kind) % 2**32)
     n = 48
     eng = DeltaEngine(n, pruned=(kind != "warm"))
@@ -287,7 +305,10 @@ def test_engine_oracle_parity_with_tracing_enabled(fresh_tracer, kind):
     assert AUDITOR.audited_steady_recompiles == steady_before, (
         f"steady recompiles: {AUDITOR.steady_records()}")
     ring = fresh_tracer.ring()
-    assert {"ingest", "query"} <= {r.name for r in ring}
+    if not tracing:
+        assert ring == [] and fresh_tracer.registry.metrics() == []
+        return
+    assert PHASE_SPANS[kind] <= {r.name for r in ring}
     assert all(r.labels["tenant"] == f"oracle-{kind}" for r in ring)
 
 
@@ -357,3 +378,156 @@ def test_first_call_vs_steady_split(fresh_tracer):
 
 def test_compile_count_routes_through_auditor():
     assert DeltaEngine.compile_count() == AUDITOR.total_compile_count()
+
+
+# ---------------------------------------------------------------------------
+# phase spans: label inheritance, one id per request, transfer counters
+# ---------------------------------------------------------------------------
+def test_children_inherit_labels_share_trace_id_and_tally():
+    tr = Tracer(profiler_bridge=False)
+    with tr.span("service", op="density", tenant="a"):
+        with tr.span("query", tenant="a", engine="delta"):
+            with tr.span("prune_prepare"):
+                tr.tally("d2h_bytes", 256)
+                tr.tally("d2h_bytes", 4)
+    with tr.span("other"):
+        pass
+    tr.tally("h2d_bytes", 8)          # no span open: nothing to add to
+    recs = {r.name: r for r in tr.ring()}
+    # a span with no labels takes its parent's; given labels stay as given
+    assert recs["prune_prepare"].labels == {"tenant": "a", "engine": "delta"}
+    assert recs["service"].labels == {"op": "density", "tenant": "a"}
+    assert recs["other"].labels == {}
+    assert recs["prune_prepare"].attrs == {"d2h_bytes": 260}
+    root = recs["service"].span_id
+    assert {recs[n].trace_id for n in ("service", "query",
+                                       "prune_prepare")} == {root}
+    assert recs["other"].trace_id == recs["other"].span_id != root
+    assert (recs["service"].t_mono_ns <= recs["query"].t_mono_ns
+            <= recs["prune_prepare"].t_mono_ns)
+    assert tr.registry.counter("d2h_bytes_total", tenant="a",
+                               engine="delta").value == 260
+    assert tr.registry.find("h2d_bytes_total") == []
+    rec = recs["prune_prepare"]
+    assert SpanRecord.from_json(json.loads(json.dumps(rec.to_json()))) == rec
+
+
+def _children(ring, parent):
+    return sorted((r for r in ring if r.parent_id == parent.span_id),
+                  key=lambda r: r.t_mono_ns)
+
+
+def _interval(rec):
+    return rec.t_mono_ns, rec.t_mono_ns + rec.duration_ms * 1e6
+
+
+def test_ingest_span_tree(fresh_tracer):
+    """service -> ingest -> {buffer_apply, ingest_dispatch}: the children
+    lie inside their parent, one after the other, under one trace id."""
+    svc = StreamService()
+    assert svc.create_tenant("tree", 64).ok
+    rng = np.random.default_rng(5)
+    for _ in range(3):
+        assert svc.apply_updates("tree", insert=rng.integers(0, 64, (40, 2)),
+                                 delete=rng.integers(0, 64, (8, 2))).ok
+    ring = fresh_tracer.ring()
+    service = [r for r in ring if r.name == "service"
+               and r.labels["op"] == "apply_updates"][-1]
+    (ingest,) = _children(ring, service)
+    assert ingest.name == "ingest"
+    assert ingest.labels == {"tenant": "tree", "engine": "delta"}
+    kids = _children(ring, ingest)
+    assert [k.name for k in kids] == ["buffer_apply", "ingest_dispatch"]
+    end = _interval(ingest)[0]
+    for k in kids:
+        lo, hi = _interval(k)
+        assert end <= lo <= hi <= _interval(ingest)[1]
+        end = hi
+    assert {r.trace_id for r in (service, ingest, *kids)} == {service.span_id}
+    applied = kids[0].attrs
+    assert applied["n_inserted"] + applied["n_dup_inserts"] == 40
+    assert applied["n_deleted"] + applied["n_absent_deletes"] == 8
+    assert "n_inserted" not in ingest.attrs   # counted once, on the child
+
+
+def test_h2d_bytes_match_the_uploaded_arrays(fresh_tracer):
+    """A plain batch uploads six int32 rows of the padded width; a regrow
+    re-uploads the symmetric slot arrays and the degree array whole."""
+    n = 64
+    eng = DeltaEngine(n, capacity=256, pruned=False)
+    eng.tenant = "bytes"
+    pairs = np.array([(u, v) for u in range(n) for v in range(u + 1, n)])
+    order = np.random.default_rng(9).permutation(len(pairs))
+    eng.apply_updates(insert=pairs[order[:100]])     # first: resync + batch
+    eng.apply_updates(insert=pairs[order[100:150]])  # plain batch
+    ring = fresh_tracer.ring()
+    dispatch = [r for r in ring if r.name == "ingest_dispatch"][-1]
+    b = dispatch.attrs["batch_width"]
+    assert b == 64 and dispatch.attrs["h2d_bytes"] == 6 * 4 * b
+
+    eng.apply_updates(insert=pairs[order[150:400]])  # past 256 slots: regrow
+    ring = fresh_tracer.ring()
+    ingest = [r for r in ring if r.name == "ingest"][-1]
+    kids = _children(ring, ingest)
+    assert [k.name for k in kids] == ["buffer_apply", "engine_resync"]
+    assert eng.buffer.capacity == 512
+    src, dst, deg = eng.buffer.resident_state(eng.node_capacity)
+    assert kids[1].attrs["h2d_bytes"] == src.nbytes + dst.nbytes + deg.nbytes
+    assert kids[1].attrs["h2d_bytes"] == 2 * 4 * 1024 + 4 * n
+    total = sum(r.attrs.get("h2d_bytes", 0) for r in ring)
+    assert fresh_tracer.registry.counter(
+        "h2d_bytes_total", tenant="bytes", engine="delta").value == total
+
+
+def _planted_engine(n=64):
+    eng = DeltaEngine(n)
+    rng = np.random.default_rng(4)
+    clique = [(u, v) for u in range(12) for v in range(u + 1, 12)]
+    eng.apply_updates(insert=np.concatenate(
+        [np.array(clique), rng.integers(0, n, (60, 2))]))
+    return eng
+
+
+def test_pruned_query_spans_carry_tenant_and_path(fresh_tracer):
+    eng = _planted_engine()
+    eng.tenant = "pq"
+    q = eng.query()
+    assert q.pruned
+    ring = fresh_tracer.ring()
+    query = [r for r in ring if r.name == "query"][-1]
+    assert query.attrs["path"] == "pruned"
+    kids = _children(ring, query)
+    assert [k.name for k in kids] == ["prune_plan", "prune_prepare",
+                                      "prune_peel", "prune_merge"]
+    for k in kids:
+        assert k.labels == {"tenant": "pq", "engine": "delta"}
+        assert k.trace_id == query.trace_id
+    prepare, peel, merge = kids[1:]
+    assert prepare.attrs["d2h_bytes"] == 4 * eng.node_capacity  # int32 deg
+    assert "fell_back" not in prepare.attrs
+    v, e = eng._plan.bucket_v, eng._plan.bucket_e
+    assert peel.attrs["h2d_bytes"] == 2 * 4 * e   # the bucket's two lanes
+    assert merge.attrs["passes"] == q.passes
+    assert merge.attrs.get("d2h_bytes", v) == v   # the bucket mask, if read
+    assert "candidate_fraction" in kids[0].attrs
+
+
+def test_forced_prune_fallback_runs_peel_full(fresh_tracer, monkeypatch):
+    from repro.core import prune
+
+    monkeypatch.setattr(prune, "prepare_pruned_peel", lambda *a, **k: None)
+    eng = _planted_engine()
+    eng.tenant = "fb"
+    q = eng.query()
+    assert not q.pruned and eng.metrics.n_prune_fallbacks == 1
+    ring = fresh_tracer.ring()
+    query = [r for r in ring if r.name == "query"][-1]
+    assert query.attrs["path"] == "warm"
+    kids = _children(ring, query)
+    assert [k.name for k in kids] == ["prune_plan", "prune_prepare",
+                                      "peel_full"]
+    assert kids[1].attrs["fell_back"] is True
+    assert kids[2].attrs["passes"] == q.passes
+    assert kids[2].attrs["d2h_bytes"] >= eng.node_capacity   # the bool mask
+    rho, _, passes = pbahmani_np(eng.buffer.to_graph())
+    assert q.density == pytest.approx(rho, rel=1e-6) and q.passes == passes

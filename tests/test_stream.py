@@ -192,6 +192,87 @@ def test_buffer_hole_reuse_keeps_fragmentation_low():
     assert buf.n_edges == 3
 
 
+class _PlainSlots:
+    """The slot layout spelled out one pair at a time: deletes first, in
+    batch order; inserts deduplicated in (u, v) order, each taking the
+    newest hole, else the lowest never-used slot; a compaction lays the
+    live pairs out sorted from slot 0."""
+
+    def __init__(self, n, capacity):
+        self.n, self.capacity = n, capacity
+        self.slot, self.holes = {}, []
+        self.fresh = list(range(capacity - 1, -1, -1))
+
+    def apply(self, insert, delete):
+        dels, ins = [], []
+        for u, v in (delete if delete is not None else []):
+            key = (min(int(u), int(v)), max(int(u), int(v)))
+            if key[0] != key[1] and key in self.slot:
+                s = self.slot.pop(key)
+                self.holes.append(s)
+                dels.append((key, s))
+        keys = sorted({(min(int(u), int(v)), max(int(u), int(v)))
+                       for u, v in (insert if insert is not None else [])
+                       if u != v})
+        new = [k for k in keys if k not in self.slot]
+        if len(self.slot) + len(new) > self.capacity:
+            grown = max(next_pow2(len(self.slot) + len(new)),
+                        2 * self.capacity)
+            self.fresh = list(range(grown - 1, self.capacity - 1, -1)) + \
+                self.fresh
+            self.capacity = grown
+        for k in new:
+            s = self.holes.pop() if self.holes else self.fresh.pop()
+            self.slot[k] = s
+            ins.append((k, s))
+        return ins, dels
+
+    def compact(self):
+        self.slot = {k: i for i, k in enumerate(sorted(self.slot))}
+        self.fresh = list(range(self.capacity - 1, len(self.slot) - 1, -1))
+        self.holes = []
+
+    def arrays(self):
+        u = np.full(self.capacity, self.n, np.int32)
+        v = np.full(self.capacity, self.n, np.int32)
+        for (a, b), s in self.slot.items():
+            u[s], v[s] = a, b
+        return u, v
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_buffer_slot_layout_matches_plain_model(seed):
+    """The batch-at-once buffer hands out exactly the slots of the
+    one-pair-at-a-time model, through growth, churn and compaction: the
+    engine patches device lanes by these slot numbers."""
+    rng = np.random.default_rng(seed)
+    n = 40
+    buf = EdgeBuffer(n_nodes=n, capacity=256, compact_threshold=None)
+    model = _PlainSlots(n, 256)
+    for step in range(30):
+        ins = rng.integers(0, n, (int(rng.integers(0, 200)), 2))
+        live = sorted(model.slot)
+        dels = None
+        if live and rng.random() < 0.8:
+            pick = rng.integers(0, len(live), int(rng.integers(1, 60)))
+            dels = np.concatenate([np.asarray(live)[pick][:, ::-1],
+                                   rng.integers(0, n, (4, 2))])
+        got = buf.apply(insert=ins, delete=dels)
+        want_ins, want_del = model.apply(ins, dels)
+        assert [tuple(p) for p in got[0].tolist()] == [k for k, _ in want_ins]
+        assert got[1].tolist() == [s for _, s in want_ins]
+        assert [tuple(p) for p in got[2].tolist()] == [k for k, _ in want_del]
+        assert got[3].tolist() == [s for _, s in want_del]
+        if step % 7 == 6:
+            buf.epoch_compact()
+            model.compact()
+        u, v = buf.host_view()
+        mu, mv = model.arrays()
+        assert buf.capacity == model.capacity, step
+        assert np.array_equal(u, mu) and np.array_equal(v, mv), step
+        assert buf.n_edges == len(model.slot)
+
+
 # ---------------------------------------------------------------------------
 # DeltaEngine: the incremental == from-scratch oracle
 # ---------------------------------------------------------------------------

@@ -2,7 +2,7 @@
 exactness (vs a pooled oracle, property-tested), both transports, the
 scrape endpoint under the strict exposition lint, label-escaping
 round-trips, JSONL sink rotation, multi-window burn-rate alerts on a fake
-clock, gated OTLP export — and the hard invariant that a live scrape
+clock — and the hard invariant that a live scrape
 server plus collector push cannot perturb engine results or compile
 caches (oracle parity with the whole plane up)."""
 import json
@@ -24,18 +24,15 @@ from repro.obs import (
     CollectorServer,
     Histogram,
     MetricsRegistry,
-    OtlpExporter,
     SloMonitor,
     Tracer,
     burn_exceeds,
     escape_label_value,
-    otel_available,
     parse_prometheus_text,
     prometheus_text,
     push_snapshot,
     serve_metrics,
     set_tracer,
-    span,
     unescape_label_value,
     write_spool,
 )
@@ -489,75 +486,6 @@ def test_gap_freshness_stale_and_missing():
     assert not fresh["us"]["stale"] and fresh["us"]["age_s"] is None
     g.updated_at = 900.0               # certificate 100s ago: healthy
     assert not mon.evaluate()["freshness"]["eu"]["stale"]
-
-
-# ---------------------------------------------------------------------------
-# OTLP export: gated on SDK importability, counted no-op otherwise
-# ---------------------------------------------------------------------------
-def test_otlp_noop_is_counted_when_sdk_missing():
-    reg = MetricsRegistry()
-    reg.histogram("query_ms", tenant="eu").observe(1.0)
-    exp = OtlpExporter(registry=reg)
-    exp.available = False              # force the no-SDK path either way
-    assert exp.export_spans([]) == 0
-    assert exp.export_metrics() == 0
-    noop = reg.counter("otlp_export_noop_total", exporter="otlp")
-    assert noop.value == 2
-    assert exp.n_spans_exported == exp.n_metrics_exported == 0
-
-
-def test_otlp_export_failure_is_counted_never_raises():
-    class _Boom:
-        def export(self, *_a, **_k):
-            raise RuntimeError("collector down")
-
-    reg = MetricsRegistry()
-    reg.counter("peel_passes_total", tenant="eu").inc(1)
-    exp = OtlpExporter(registry=reg, span_exporter=_Boom(),
-                       metric_exporter=_Boom())
-    exp.available = True               # force past the gate: errors must
-    assert exp.export_metrics() == 0   # be swallowed and counted
-    errs = reg.counter("otlp_export_errors_total", exporter="otlp")
-    assert errs.value >= 1
-
-
-@pytest.mark.skipif(not otel_available(),
-                    reason="opentelemetry-sdk not installed")
-def test_otlp_real_sdk_export_is_lossless(fresh_tracer):
-    class _Capture:
-        def __init__(self):
-            self.batches = []
-
-        def export(self, batch, **_kw):
-            self.batches.append(batch)
-            return True
-
-    with span("query", tenant="eu") as sp:
-        sp.attrs["compiled"] = True
-        with span("peel", tenant="eu"):
-            pass
-    reg = fresh_tracer.registry
-    spans_out, metrics_out = _Capture(), _Capture()
-    exp = OtlpExporter(registry=reg, span_exporter=spans_out,
-                       metric_exporter=metrics_out)
-    n = exp.export_spans(fresh_tracer.ring())
-    assert n == 2 and len(spans_out.batches) == 1
-    readable = spans_out.batches[0]
-    by_name = {s.name: s for s in readable}
-    assert by_name["peel"].parent is not None
-    assert by_name["peel"].parent.span_id == by_name["query"].context.span_id
-    assert by_name["query"].attributes["compiled"] is True
-
-    assert exp.export_metrics() > 0
-    data = metrics_out.batches[0]
-    sm = data.resource_metrics[0].scope_metrics[0]
-    hists = {m.name: m for m in sm.metrics
-             if m.name.endswith("_ms") or m.name.endswith("_first_call_ms")}
-    src = reg.find("peel_ms")[0]
-    point = hists["peel_ms"].data.data_points[0]
-    assert tuple(point.bucket_counts) == tuple(src.counts)   # lossless
-    assert tuple(point.explicit_bounds) == tuple(src.bounds)
-    assert point.count == src.total
 
 
 # ---------------------------------------------------------------------------
