@@ -52,7 +52,7 @@ def _mixed_batch(rng, eng, n_nodes, batch_size):
     graph churns at roughly constant |E| — tenants stay in their capacity
     bucket for the whole measured window (no mid-measure regrow)."""
     ins = rng.integers(0, n_nodes, (batch_size // 2, 2))
-    pool = np.asarray(sorted(eng.buffer._slot))
+    pool = eng.buffer.live_pairs()
     k = min(batch_size // 2, len(pool))
     dels = pool[rng.choice(len(pool), k, replace=False)]
     return ins, dels

@@ -93,7 +93,7 @@ def main():
             # old transactions age out of the sliding window
             eng = svc.registry.get(region)
             if eng.n_edges > 4000:
-                stale_edges = np.asarray(sorted(eng.buffer._slot))[:250]
+                stale_edges = eng.buffer.live_pairs()[:250]
                 svc.apply_updates(region, delete=stale_edges)
         if step >= RING_STARTS:
             svc.apply_updates("payments-eu", insert=ring_batch(rng, ring_ids))

@@ -11,9 +11,9 @@ so a graph that grows through k batches passes through at most log2 distinct
 shapes — every other batch is a jit cache hit (the "no recompiles on the hot
 path" contract, asserted in tests/test_stream.py).
 
-Deletions punch holes (slot -> sentinel) instead of compacting, keeping
-update cost O(batch); freed slots are recycled hole-first for later
-insertions. The ``epoch_compact`` hook rebuilds a dense prefix when the
+Deletions punch holes (slot -> sentinel) instead of compacting, so a
+batch moves O(batch) slots and device lanes; freed slots are recycled
+hole-first for later insertions. The ``epoch_compact`` hook rebuilds a dense prefix when the
 delta engine runs its staleness refresh, and with ``shrink=True`` also
 *halves capacity down* to the smallest pow-2 that keeps 2x headroom — the
 ISSUE 3 bugfix for sliding-window/delete-heavy tenants that otherwise kept
@@ -27,9 +27,14 @@ exceeds ``compact_threshold`` the buffer compacts itself mid-stream
 (bumping ``generation`` so resident device state and compiled executables
 re-bucket correctly).
 
-Host-side membership is a dict keyed on the canonical pair (min, max), the
-streaming analog of the paper's "super map": arbitrary update order, O(1)
-dedup, O(1) delete.
+Host-side membership is the streaming analog of the paper's "super map": a
+sorted int64 array of the live canonical keys ``u * n_nodes + v`` (u < v),
+with each key's slot in an aligned int32 array. A batch is a handful of
+whole-array passes — a sort, ``searchsorted`` lookups, one boolean
+compress for the deletes and one merge for the inserts — so its cost is
+O(batch * log E + E) memmove, with no Python step per pair.
+Sorted keys are also the compacted layout: ``epoch_compact`` writes them
+into the dense prefix as they are.
 """
 from __future__ import annotations
 
@@ -62,12 +67,16 @@ class EdgeBuffer:
         self.compact_threshold = compact_threshold
         self._u = np.full(capacity, n_nodes, dtype=np.int32)
         self._v = np.full(capacity, n_nodes, dtype=np.int32)
-        self._slot: dict[tuple[int, int], int] = {}
-        # never-used slots, popped in ascending order; freed slots (holes)
-        # live separately so fragmentation is observable and holes recycle
-        # first (dense prefixes survive churn longer)
-        self._fresh: list[int] = list(range(capacity - 1, -1, -1))
-        self._holes: list[int] = []
+        # membership: live keys u * n_nodes + v (u < v), ascending, and
+        # each key's slot
+        self._keys = np.empty(0, dtype=np.int64)
+        self._kslot = np.empty(0, dtype=np.int32)
+        # never-used slots are always the range [_fresh, capacity), taken
+        # lowest first; freed slots (holes) are a stack, newest on top, so
+        # fragmentation is observable and holes recycle first (dense
+        # prefixes survive churn longer)
+        self._fresh = 0
+        self._holes = np.empty(0, dtype=np.int32)
         self.generation = 0  # bumped on every grow/compact (shape/layout epoch)
         self._version = 0    # bumped on every mutation (sorted-view cache key)
         self._sorted_cache: tuple | None = None
@@ -75,7 +84,7 @@ class EdgeBuffer:
     # -- properties ---------------------------------------------------------
     @property
     def n_edges(self) -> int:
-        return len(self._slot)
+        return self._keys.size
 
     @property
     def sentinel(self) -> int:
@@ -84,14 +93,25 @@ class EdgeBuffer:
     @property
     def tombstone_fraction(self) -> float:
         """Fraction of the slot space holding un-recycled delete holes."""
-        return len(self._holes) / self.capacity
+        return self._holes.size / self.capacity
 
     def __contains__(self, edge: tuple[int, int]) -> bool:
-        u, v = int(edge[0]), int(edge[1])
-        return (min(u, v), max(u, v)) in self._slot
+        u, v = sorted((int(edge[0]), int(edge[1])))
+        if not 0 <= u < v < self.n_nodes:
+            return False
+        key = u * self.n_nodes + v
+        i = int(np.searchsorted(self._keys, key))
+        return i < self._keys.size and int(self._keys[i]) == key
+
+    def live_pairs(self) -> np.ndarray:
+        """The live edges as canonical pairs (u < v), ``[n_edges, 2]``
+        int64, sorted by (u, v)."""
+        return np.stack(np.divmod(self._keys, self.n_nodes), axis=1)
 
     # -- mutation -----------------------------------------------------------
-    def _canonicalize(self, edges: np.ndarray) -> np.ndarray:
+    def _keys_of(self, edges: np.ndarray) -> np.ndarray:
+        """Canonical keys ``u * n_nodes + v`` (u < v) of a batch, in batch
+        order; self-loops dropped (simple-graph convention)."""
         edges = np.asarray(edges, dtype=np.int64).reshape(-1, 2)
         if edges.size and (edges.min() < 0 or edges.max() >= self.n_nodes):
             raise ValueError(
@@ -100,8 +120,14 @@ class EdgeBuffer:
             )
         u = np.minimum(edges[:, 0], edges[:, 1])
         v = np.maximum(edges[:, 0], edges[:, 1])
-        keep = u != v  # simple-graph convention: drop self-loops
-        return np.stack([u[keep], v[keep]], axis=1)
+        return (u * self.n_nodes + v)[u != v]
+
+    def _find(self, key: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
+        """Index positions of the ascending ``key`` and which are live."""
+        pos = np.searchsorted(self._keys, key)
+        if not self._keys.size:
+            return pos, np.zeros(key.size, dtype=bool)
+        return pos, self._keys[np.minimum(pos, self._keys.size - 1)] == key
 
     def apply(
         self, insert: np.ndarray | None = None, delete: np.ndarray | None = None
@@ -111,71 +137,82 @@ class EdgeBuffer:
         inserts already present and deletes of absent edges are dropped.
         Deletes are applied first (stream semantics: a batch is a set of
         retractions followed by assertions), so an insert may reuse a slot
-        freed by a delete in the same batch. Slot indices let the delta
-        engine patch its device-resident arrays in O(batch).
+        freed by a delete in the same batch. Deletes come back in batch
+        order (a repeated pair at its first position), inserts in (u, v)
+        order. Slot indices let the delta engine patch its device-resident
+        arrays in O(batch).
 
         If the batch leaves the tombstone fraction above
         ``compact_threshold`` the buffer compacts itself before returning
         (``generation`` bumps, so callers holding device state must resync —
         the returned slot indices refer to the pre-compaction layout)."""
-        # the loops touch only the dict; the slot arrays are written once
-        # per batch. Pairs cross as tuples of Python ints zipped from two
-        # flat ``tolist`` columns: no numpy scalars, and no row lists for
-        # the collector to carry
-        table = self._slot
-        deleted, del_slots = [], []
+        n = self.n_nodes
+        deleted = del_slots = np.empty(0, dtype=np.int64)
         if delete is not None:
-            pop = table.pop
-            dl = self._canonicalize(delete)
-            for key in zip(dl[:, 0].tolist(), dl[:, 1].tolist()):
-                slot = pop(key, None)
-                if slot is not None:
-                    deleted.append(key)
-                    del_slots.append(slot)
-            idx = np.asarray(del_slots, dtype=np.int64)
-            self._u[idx] = self.sentinel
-            self._v[idx] = self.sentinel
-            self._holes.extend(del_slots)
-        inserted, ins_slots = [], []
+            key = self._keys_of(delete)
+            order = np.argsort(key)
+            key = key[order]
+            # one entry per distinct key, tagged with its first batch index
+            run = np.flatnonzero(np.diff(key, prepend=-1))
+            first = np.minimum.reduceat(order, run) if run.size else run
+            pos, live = self._find(key[run])
+            # back to batch order (the first indices are distinct)
+            pos = pos[live][np.argsort(first[live])]
+            deleted = self._keys[pos]
+            del_slots = self._kslot[pos]
+            keep = np.ones(self._keys.size, dtype=bool)
+            keep[pos] = False
+            self._keys = self._keys[keep]
+            self._kslot = self._kslot[keep]
+            self._u[del_slots] = self.sentinel
+            self._v[del_slots] = self.sentinel
+            self._holes = np.concatenate([self._holes, del_slots])
+        inserted = ins_slots = np.empty(0, dtype=np.int64)
         if insert is not None:
-            ins = self._canonicalize(insert)
-            if ins.size:
-                # unique pairs in (u, v) order, as one int64 key each
-                key = np.unique(ins[:, 0] * self.n_nodes + ins[:, 1])
-                ins = np.stack([key // self.n_nodes, key % self.n_nodes], 1)
-            inserted = [k for k in zip(ins[:, 0].tolist(), ins[:, 1].tolist())
-                        if k not in table]
+            key = np.unique(self._keys_of(insert))
+            pos, live = self._find(key)
+            inserted, pos = key[~live], pos[~live]
             # grow once, up front, if the effective batch cannot fit
-            if len(table) + len(inserted) > self.capacity:
-                self._grow(next_pow2(len(table) + len(inserted)))
-            ins_slots = self._take_slots(len(inserted))
-            table.update(zip(inserted, ins_slots))
-            pairs = np.asarray(inserted, dtype=np.int32).reshape(-1, 2)
-            idx = np.asarray(ins_slots, dtype=np.int64)
-            self._u[idx] = pairs[:, 0]
-            self._v[idx] = pairs[:, 1]
+            if self._keys.size + inserted.size > self.capacity:
+                self._grow(next_pow2(self._keys.size + inserted.size))
+            ins_slots = self._take_slots(inserted.size)
+            self._merge(pos, inserted, ins_slots)
+            self._u[ins_slots], self._v[ins_slots] = np.divmod(inserted, n)
         self._version += 1
         if (self.compact_threshold is not None
-                and len(self._holes) > self.compact_threshold * self.capacity):
+                and self._holes.size > self.compact_threshold * self.capacity):
             self.epoch_compact()
         return (
-            np.asarray(inserted, dtype=np.int32).reshape(-1, 2),
-            np.asarray(ins_slots, dtype=np.int32),
-            np.asarray(deleted, dtype=np.int32).reshape(-1, 2),
-            np.asarray(del_slots, dtype=np.int32),
+            np.stack(np.divmod(inserted, n), 1).astype(np.int32),
+            ins_slots.astype(np.int32),
+            np.stack(np.divmod(deleted, n), 1).astype(np.int32),
+            del_slots.astype(np.int32),
         )
 
-    def _take_slots(self, k: int) -> list[int]:
-        """``k`` free slots: the newest holes first, then the lowest fresh
-        slots (the order of popping each list from its end)."""
-        holes, fresh = self._holes, self._fresh
-        h = min(k, len(holes))
-        taken = holes[len(holes) - h:][::-1]
-        del holes[len(holes) - h:]
-        f = k - h
-        if f:
-            taken += fresh[len(fresh) - f:][::-1]
-            del fresh[len(fresh) - f:]
+    def _merge(self, pos: np.ndarray, key: np.ndarray,
+               slots: np.ndarray) -> None:
+        """Insert the ascending absent ``key`` (with their ``slots``) into
+        the index before positions ``pos``: one pass, ``np.insert``'s
+        result without its per-call overhead."""
+        at = pos + np.arange(pos.size)
+        old = np.ones(self._keys.size + pos.size, dtype=bool)
+        old[at] = False
+        keys = np.empty(old.size, dtype=np.int64)
+        kslot = np.empty(old.size, dtype=np.int32)
+        keys[at], keys[old] = key, self._keys
+        kslot[at], kslot[old] = slots, self._kslot
+        self._keys, self._kslot = keys, kslot
+
+    def _take_slots(self, k: int) -> np.ndarray:
+        """``k`` free slots: the newest holes first, then the lowest
+        never-used slots."""
+        h = min(k, self._holes.size)
+        rest = self._holes.size - h
+        taken = np.concatenate([
+            self._holes[rest:][::-1],
+            np.arange(self._fresh, self._fresh + k - h, dtype=np.int32)])
+        self._holes = self._holes[:rest]
+        self._fresh += k - h
         return taken
 
     def _grow(self, new_capacity: int) -> None:
@@ -184,8 +221,8 @@ class EdgeBuffer:
         v = np.full(new_capacity, self.sentinel, dtype=np.int32)
         u[: self.capacity] = self._u
         v[: self.capacity] = self._v
-        self._fresh = (list(range(new_capacity - 1, self.capacity - 1, -1))
-                       + self._fresh)
+        # the new slots [capacity, new_capacity) extend the never-used
+        # range [_fresh, capacity) as they are
         self._u, self._v = u, v
         self.capacity = new_capacity
         self.generation += 1
@@ -213,12 +250,6 @@ class EdgeBuffer:
                 target = self.shrink_target()
                 if target is not None:
                     new_capacity = target
-            # the live pairs are the slots off the sentinel; sorted by
-            # (u, v) they fill the dense prefix
-            live = np.flatnonzero(self._u != self.sentinel)
-            u, v = self._u[live], self._v[live]
-            order = np.lexsort((v, u))
-            u, v = u[order], v[order]
             if new_capacity != self.capacity:
                 self._u = np.full(new_capacity, self.sentinel, np.int32)
                 self._v = np.full(new_capacity, self.sentinel, np.int32)
@@ -227,12 +258,13 @@ class EdgeBuffer:
                 self._v.fill(self.sentinel)
             shrunk = new_capacity != self.capacity
             self.capacity = new_capacity
-            n = u.size
-            self._u[:n] = u
-            self._v[:n] = v
-            self._slot = dict(zip(zip(u.tolist(), v.tolist()), range(n)))
-            self._fresh = list(range(self.capacity - 1, n - 1, -1))
-            self._holes = []
+            # the sorted keys are the live pairs in (u, v) order: they
+            # fill the dense prefix as they are
+            n = self._keys.size
+            self._u[:n], self._v[:n] = np.divmod(self._keys, self.n_nodes)
+            self._kslot = np.arange(n, dtype=np.int32)
+            self._fresh = n
+            self._holes = np.empty(0, dtype=np.int32)
             self.generation += 1
             self._version += 1
             sp.set("n_edges", n).set("shrunk", shrunk)
@@ -300,10 +332,7 @@ class EdgeBuffer:
 
     def to_graph(self) -> Graph:
         """Materialize an immutable Graph (compacted) — the oracle view."""
-        if not self._slot:
-            return Graph.from_edges(np.zeros((0, 2), np.int64), n_nodes=self.n_nodes)
-        pairs = np.asarray(sorted(self._slot), dtype=np.int64)
-        return Graph.from_edges(pairs, n_nodes=self.n_nodes)
+        return Graph.from_edges(self.live_pairs(), n_nodes=self.n_nodes)
 
     def __repr__(self) -> str:  # pragma: no cover
         return (

@@ -222,12 +222,12 @@ def test_engine_pruned_zero_recompiles_with_refresh():
     eng.refresh()   # adapts buckets to the observed handoff
     # warm the churn-batch shape and the adapted bucket executable
     eng.apply_updates(insert=rng.integers(0, 500, (20, 2)),
-                      delete=np.asarray(sorted(eng.buffer._slot))[:20])
+                      delete=eng.buffer.live_pairs()[:20])
     eng.query()
     before = DeltaEngine.compile_count()
     for _ in range(10):
         ins = rng.integers(0, 500, (20, 2))
-        dels = np.asarray(sorted(eng.buffer._slot))[:20]  # stationary churn
+        dels = eng.buffer.live_pairs()[:20]  # stationary churn
         eng.apply_updates(insert=ins, delete=dels)
         eng.query()
     eng.refresh()
@@ -283,7 +283,7 @@ def test_engine_mid_epoch_bucket_shrink():
     be_before = eng.metrics.prune_bucket_e
 
     # contract hard mid-epoch: drop ~95% of edges, keep the planted block
-    pool = np.asarray(sorted(eng.buffer._slot))
+    pool = eng.buffer.live_pairs()
     dels = pool[rng.random(len(pool)) >= 0.05]
     for i in range(0, len(dels), 512):
         eng.apply_updates(delete=dels[i: i + 512])

@@ -102,7 +102,7 @@ def test_sharded_zero_recompiles_after_warmup():
     before = DeltaEngine.compile_count()
     for _ in range(10):
         ins = rng.integers(0, 500, (30, 2))
-        dels = np.asarray(sorted(eng.buffer._slot))[:10]
+        dels = eng.buffer.live_pairs()[:10]
         eng.apply_updates(insert=ins, delete=dels)
         eng.query()
     assert DeltaEngine.compile_count() == before, "sharded hot path recompiled"
@@ -221,7 +221,7 @@ eng.query()
 before = DeltaEngine.compile_count()
 for _ in range(6):
     ins = rng.integers(0, n, (30, 2))
-    dels = np.asarray(sorted(eng.buffer._slot))[:10]
+    dels = eng.buffer.live_pairs()[:10]
     eng.apply_updates(insert=ins, delete=dels)
     eng.query()
 assert DeltaEngine.compile_count() == before, "multi-device path recompiled"

@@ -8,6 +8,8 @@ The two load-bearing claims (ISSUE 1 acceptance criteria):
   * repeated same-capacity update batches trigger ZERO recompilations after
     warmup (the shape-bucketing contract).
 """
+import zlib
+
 import numpy as np
 import pytest
 
@@ -94,11 +96,11 @@ def test_buffer_compact_preserves_graph():
     buf = EdgeBuffer(n_nodes=50)
     rng = np.random.default_rng(1)
     buf.apply(insert=rng.integers(0, 50, (200, 2)))
-    pool = np.asarray(sorted(buf._slot))[::3]
+    pool = buf.live_pairs()[::3]
     buf.apply(delete=pool)
-    before = sorted(buf._slot)
+    before = buf.live_pairs()
     buf.epoch_compact()
-    assert sorted(buf._slot) == before
+    assert np.array_equal(buf.live_pairs(), before)
     src, _ = buf.device_view()
     # compaction is hole-free: the valid prefix is dense
     assert (src[: buf.n_edges] < buf.sentinel).all()
@@ -129,7 +131,7 @@ def test_buffer_epoch_shrink_with_hysteresis():
     assert buf.capacity == 1024
 
     # contract far below the floor: shrink to next_pow2(2*live)
-    pool = np.asarray(sorted(buf._slot))
+    pool = buf.live_pairs()
     buf.apply(delete=pool[60:])
     assert buf.n_edges == 60
     before = buf.to_graph()
@@ -157,7 +159,7 @@ def test_buffer_tombstone_autocompact():
     buf.apply(insert=rng.integers(0, 100, (250, 2)))
     n0 = buf.n_edges
     gen0 = buf.generation
-    pool = np.asarray(sorted(buf._slot))
+    pool = buf.live_pairs()
     buf.apply(delete=pool[: n0 - 50])  # way past 0.3 * 256 holes
     assert buf.generation > gen0                 # compaction happened
     assert buf.tombstone_fraction == 0.0         # holes cleared
@@ -169,7 +171,7 @@ def test_buffer_tombstone_autocompact():
     buf2 = EdgeBuffer(n_nodes=100, capacity=256, compact_threshold=0.5)
     buf2.apply(insert=rng.integers(0, 100, (100, 2)))
     gen1 = buf2.generation
-    pool2 = np.asarray(sorted(buf2._slot))
+    pool2 = buf2.live_pairs()
     buf2.apply(delete=pool2[:20])
     assert buf2.generation == gen1
     assert buf2.tombstone_fraction > 0.0
@@ -178,7 +180,7 @@ def test_buffer_tombstone_autocompact():
     buf3 = EdgeBuffer(n_nodes=100, capacity=256, compact_threshold=None)
     buf3.apply(insert=rng.integers(0, 100, (250, 2)))
     gen3 = buf3.generation
-    buf3.apply(delete=np.asarray(sorted(buf3._slot)))
+    buf3.apply(delete=buf3.live_pairs())
     assert buf3.generation == gen3
 
 
@@ -196,12 +198,15 @@ class _PlainSlots:
     """The slot layout spelled out one pair at a time: deletes first, in
     batch order; inserts deduplicated in (u, v) order, each taking the
     newest hole, else the lowest never-used slot; a compaction lays the
-    live pairs out sorted from slot 0."""
+    live pairs out sorted from slot 0, and runs after a batch that leaves
+    more than ``compact_threshold`` of the slots as holes."""
 
-    def __init__(self, n, capacity):
+    def __init__(self, n, capacity, compact_threshold=None):
         self.n, self.capacity = n, capacity
+        self.compact_threshold = compact_threshold
         self.slot, self.holes = {}, []
         self.fresh = list(range(capacity - 1, -1, -1))
+        self.grown = self.compacted = 0
 
     def apply(self, insert, delete):
         dels, ins = [], []
@@ -221,10 +226,15 @@ class _PlainSlots:
             self.fresh = list(range(grown - 1, self.capacity - 1, -1)) + \
                 self.fresh
             self.capacity = grown
+            self.grown += 1
         for k in new:
             s = self.holes.pop() if self.holes else self.fresh.pop()
             self.slot[k] = s
             ins.append((k, s))
+        if (self.compact_threshold is not None
+                and len(self.holes) > self.compact_threshold * self.capacity):
+            self.compact()
+            self.compacted += 1
         return ins, dels
 
     def compact(self):
@@ -240,23 +250,60 @@ class _PlainSlots:
         return u, v
 
 
-@pytest.mark.parametrize("seed", [0, 1, 2])
+def _plain_model_batch(case, rng, n, live):
+    """One (insert, delete) batch of ``case``'s mix against the sorted
+    ``live`` pairs. The integer cases are plain random churn; the named
+    ones add their mix to it within the same batch."""
+    ins = rng.integers(0, n, (int(rng.integers(0, 200)), 2))
+    dels = None
+    if live and rng.random() < 0.8:
+        pick = rng.integers(0, len(live), int(rng.integers(1, 60)))
+        dels = np.concatenate([np.asarray(live)[pick][:, ::-1],
+                               rng.integers(0, n, (4, 2))])
+    if case == "dup_deletes" and dels is not None:
+        # each pair up to three times, in both orientations, shuffled
+        dels = rng.permutation(np.concatenate([dels, dels[:, ::-1], dels]))
+    elif case == "absent_deletes":
+        live_set = set(live)
+        absent = np.asarray([p for p in rng.integers(0, n, (40, 2)).tolist()
+                             if (min(p), max(p)) not in live_set])
+        dels = absent if dels is None else rng.permutation(
+            np.concatenate([dels, absent]))
+    elif case == "self_loops":
+        loops = np.repeat(rng.integers(0, n, (8, 1)), 2, axis=1)
+        ins = rng.permutation(np.concatenate([ins, loops]))
+        dels = loops if dels is None else rng.permutation(
+            np.concatenate([dels, loops]))
+    elif case == "delete_reinsert" and dels is not None:
+        # every other deleted pair comes back in the same batch
+        ins = rng.permutation(np.concatenate([ins, dels[::2]]))
+    elif case == "grow":
+        ins = np.concatenate([ins, rng.integers(0, n, (300, 2))])
+    elif case == "autocompact" and live and rng.random() < 0.5:
+        # a mass delete with a few inserts: its holes cross the threshold
+        dels = np.asarray(live)[rng.random(len(live)) < 0.7][:, ::-1]
+        ins = ins[: int(rng.integers(0, 20))]
+    return ins, dels
+
+
+@pytest.mark.parametrize("seed", [
+    0, 1, 2, "dup_deletes", "absent_deletes", "self_loops",
+    "delete_reinsert", "grow", "autocompact"])
 def test_buffer_slot_layout_matches_plain_model(seed):
     """The batch-at-once buffer hands out exactly the slots of the
     one-pair-at-a-time model, through growth, churn and compaction: the
-    engine patches device lanes by these slot numbers."""
-    rng = np.random.default_rng(seed)
-    n = 40
-    buf = EdgeBuffer(n_nodes=n, capacity=256, compact_threshold=None)
-    model = _PlainSlots(n, 256)
+    engine patches device lanes by these slot numbers. The named cases
+    mix, within one batch, repeated and absent deletes, self-loops, a
+    delete and re-insert of one pair, a batch that grows the buffer, and
+    batches that trip the tombstone auto-compaction."""
+    rng = np.random.default_rng(
+        seed if isinstance(seed, int) else zlib.crc32(seed.encode()))
+    n = 120 if seed == "grow" else 40
+    threshold = 0.25 if seed == "autocompact" else None
+    buf = EdgeBuffer(n_nodes=n, capacity=256, compact_threshold=threshold)
+    model = _PlainSlots(n, 256, compact_threshold=threshold)
     for step in range(30):
-        ins = rng.integers(0, n, (int(rng.integers(0, 200)), 2))
-        live = sorted(model.slot)
-        dels = None
-        if live and rng.random() < 0.8:
-            pick = rng.integers(0, len(live), int(rng.integers(1, 60)))
-            dels = np.concatenate([np.asarray(live)[pick][:, ::-1],
-                                   rng.integers(0, n, (4, 2))])
+        ins, dels = _plain_model_batch(seed, rng, n, sorted(model.slot))
         got = buf.apply(insert=ins, delete=dels)
         want_ins, want_del = model.apply(ins, dels)
         assert [tuple(p) for p in got[0].tolist()] == [k for k, _ in want_ins]
@@ -271,6 +318,46 @@ def test_buffer_slot_layout_matches_plain_model(seed):
         assert buf.capacity == model.capacity, step
         assert np.array_equal(u, mu) and np.array_equal(v, mv), step
         assert buf.n_edges == len(model.slot)
+        assert buf.tombstone_fraction == len(model.holes) / model.capacity
+    if seed == "grow":
+        assert model.grown >= 2
+    if seed == "autocompact":
+        assert model.compacted >= 2
+
+
+@settings(max_examples=10, deadline=None)
+@given(st.integers(0, 10_000), st.sampled_from([False, True]))
+def test_buffer_epoch_compact_layout_is_the_sorted_index(seed, shrink):
+    """After a compaction the index and the slots are one sorted array:
+    keys strictly increasing, key i in slot i, the sentinel past the live
+    prefix; and the never-used range [fresh, capacity) stays all sentinel
+    through the churn that follows."""
+    rng = np.random.default_rng(seed)
+    n = 60
+    buf = EdgeBuffer(n_nodes=n, capacity=1024, compact_threshold=None)
+
+    def never_used_is_sentinel():
+        u, v = buf.host_view()
+        return ((u[buf._fresh:] == buf.sentinel).all()
+                and (v[buf._fresh:] == buf.sentinel).all())
+
+    for _ in range(6):
+        live = buf.live_pairs()
+        dels = live[rng.random(len(live)) < 0.4] if len(live) else None
+        buf.apply(insert=rng.integers(0, n, (int(rng.integers(0, 150)), 2)),
+                  delete=dels)
+        assert never_used_is_sentinel()
+    buf.epoch_compact(shrink=shrink)
+    keys, m = buf._keys, buf.n_edges
+    assert (np.diff(keys) > 0).all()
+    assert np.array_equal(buf._kslot, np.arange(m))
+    u, v = buf.host_view()
+    assert np.array_equal(u[:m].astype(np.int64) * n + v[:m], keys)
+    assert (u[m:] == buf.sentinel).all() and (v[m:] == buf.sentinel).all()
+    assert buf._fresh == m and buf.tombstone_fraction == 0.0
+    buf.apply(insert=rng.integers(0, n, (40, 2)),
+              delete=buf.live_pairs()[::3])
+    assert never_used_is_sentinel()
 
 
 # ---------------------------------------------------------------------------
@@ -345,7 +432,7 @@ def test_engine_zero_recompiles_after_warmup():
     before = DeltaEngine.compile_count()
     for _ in range(12):
         ins = rng.integers(0, 500, (30, 2))
-        dels = np.asarray(sorted(eng.buffer._slot))[:10]
+        dels = eng.buffer.live_pairs()[:10]
         eng.apply_updates(insert=ins, delete=dels)
         eng.query()
     assert DeltaEngine.compile_count() == before, "hot path recompiled"
@@ -516,7 +603,7 @@ def test_engine_tombstone_autocompact_resyncs():
     # threshold is 128 holes — crossed by the delete chunks below
     ins = rng.integers(0, n, (240, 2))
     eng.apply_updates(insert=ins)
-    edges = set(eng.buffer._slot)
+    edges = set(map(tuple, eng.buffer.live_pairs().tolist()))
     n0 = len(edges)
     assert eng.buffer.capacity == 256
     pool = np.asarray(sorted(edges))

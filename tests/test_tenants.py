@@ -214,7 +214,7 @@ def test_fused_ingest_group_parity():
         r.apply_updates(insert=seedb)
         f.apply_updates(insert=seedb)
         ins = rng.integers(0, n, (25, 2))
-        dels = np.asarray(sorted(r.buffer._slot))[:5]
+        dels = r.buffer.live_pairs()[:5]
         upd[f"t{i}"] = (ins, dels)
         refs.append(r)
         fused[f"t{i}"] = f
