@@ -47,6 +47,9 @@ class ControlService:
         self.n = {}
         self.live = {}
         self.answers = {}
+        self.pending = []
+        self.results = {}
+        self.tickets = 0
 
     def create_tenant(self, tenant, n_nodes, capacity=0, **_kw):
         self.n[tenant] = int(n_nodes)
@@ -70,6 +73,11 @@ class ControlService:
         self.answers.pop(tenant, None)
         return Response(True, "apply_updates", Stats(len(ins), len(dels)))
 
+    def ingest_many(self, updates):
+        return Response(True, "ingest_many", {
+            t: self.apply_updates(t, insert=ins, delete=dels).value
+            for t, (ins, dels) in updates.items()})
+
     def _answer(self, tenant):
         from bench.reference.peel import peel
 
@@ -83,6 +91,20 @@ class ControlService:
         return Response(True, "density", {
             "density": float(d), "passes": passes, "refreshed": False,
             "pruned": False})
+
+    def submit_density(self, tenant):
+        self.pending.append((self.tickets, tenant))
+        self.tickets += 1
+        return self.tickets - 1
+
+    def flush(self):
+        pending, self.pending = self.pending, []
+        for ticket, tenant in pending:
+            self.results[ticket] = self.density(tenant)
+        return len(pending)
+
+    def poll(self, ticket):
+        return self.results.pop(ticket, None)
 
     def membership(self, tenant):
         return Response(True, "membership", {"mask": self._answer(tenant)[1]})

@@ -5,16 +5,39 @@ file found by its name in ``BENCHMARK.json``:
 
 * ``configs/<config>.json``: the deployment (sizes, service settings);
 * ``workloads/<traffic>.json``: the traffic mix, naming its generator;
-* ``traffic/<generator>.py``: a ``Traffic`` class that builds the events
-  from the seed, sets the service up, and replays the live edge set of
-  any answered query for the reference;
+* ``traffic/<generator>.py``: the generator (contract below);
 * ``metrics/<metric>.py``: a ``read(ctx)`` returning the metric's value,
   or ``None`` where the run has nothing for it to read;
 * ``reference/<reference>.py``: the configuration's plain reference.
 
-The harness drives only ``repro.stream.StreamService``, in a closed loop:
-each event is sent as soon as the previous one returned, until the window
-closes; the event running at the close completes.
+A generator module holds a class ``Traffic(config, mix, seed)`` with:
+
+* ``events``, ``warm_events``: lists of ``(op, tenant, payload)`` events,
+  the window's supply and the set-up's warm-up;
+* ``setup(make_service)``: builds the service, fills it, returns it;
+* ``live(version, tenant)``: ``(n_nodes, distinct live keys u * n + v)``
+  of ``tenant`` after ``version`` update events, warm-up included,
+  rebuilt from the generator's own log;
+* ``check_sample``: how many of the window's answers to compare.
+
+The harness drives only ``repro.stream.StreamService``'s public API, in a
+closed loop: each event is sent as soon as the previous one returned,
+until the window closes; the event running at the close completes. The
+ops:
+
+* ``("update", tenant, (insert, delete))``: ``apply_updates``;
+* ``("ingest_many", None, {tenant: (insert, delete), ...})``:
+  ``ingest_many``, one update event for the whole group;
+* ``("density", tenant, None)``: ``density``, one answer;
+* ``("density_many", None, [tenant, ...])``: ``submit_density`` for each
+  tenant, then the harness's own ``flush``, then ``poll`` for each
+  ticket: one answer per ticket, in submission order. Only that explicit
+  flush groups the tickets where the configuration sets a
+  ``coalesce_window_ms`` longer than a group takes to submit; at 0 the
+  service flushes each ticket as it is submitted.
+
+``version`` counts update events, single or grouped: every answer is
+compared with the live edge set after that many of them.
 """
 from __future__ import annotations
 
@@ -48,6 +71,8 @@ def load_module(path: str, name: str):
 class Query:
     idx: int            # position among the window's events
     tenant: str
+    pos: int = 0        # position among its event's answers
+    group: int = 1      # answers its event gave
     answered: float | None = None
     version: int = -1   # update events applied before the answer
     ok: bool = False
@@ -55,6 +80,10 @@ class Query:
     passes: int | None = None
     mask: np.ndarray | None = None
     path: str = ""
+
+    @property
+    def key(self) -> tuple:
+        return self.idx, self.pos
 
 
 @dataclass
@@ -77,30 +106,48 @@ class Context:
 
 
 class Driver:
-    """Executes events against the service and records them."""
+    """Executes events against the service and records them; with
+    ``masks`` on, every answer's member mask is read (the check may pick
+    any of them)."""
 
-    def __init__(self, svc, sample: set):
+    def __init__(self, svc, masks: bool):
         self.svc = svc
-        self.sample = sample
+        self.masks = masks
         self.version = 0
         self.queries: list[Query] = []
         self.updates: list[Update] = []
+        self.groups: dict = {"ingest_many": [], "density_many": []}
 
     def run(self, idx, op, tenant, payload):
         if op == "update":
             resp = self.svc.apply_updates(tenant, insert=payload[0],
                                           delete=payload[1])
-            self.version += 1
-            edges = (resp.value.n_inserted + resp.value.n_deleted
-                     if resp.ok else 0)
-            self.updates.append(Update(edges, resp.ok))
+            self._updated(resp, [resp.value] if resp.ok else [])
+        elif op == "ingest_many":
+            resp = self.svc.ingest_many(payload)
+            self.groups[op].append(len(payload))
+            self._updated(resp, resp.value.values() if resp.ok else [])
         elif op == "density":
             q = Query(idx=idx, tenant=tenant)
             self.queries.append(q)
             resp = self.svc.density(tenant)
             self._answer(q, resp, time.perf_counter())
+        elif op == "density_many":
+            tickets = [self.svc.submit_density(t) for t in payload]
+            self.svc.flush()
+            now = time.perf_counter()
+            self.groups[op].append(len(payload))
+            for pos, (t, ticket) in enumerate(zip(payload, tickets)):
+                q = Query(idx=idx, tenant=t, pos=pos, group=len(payload))
+                self.queries.append(q)
+                self._answer(q, self.svc.poll(ticket), now)
         else:
             raise ValueError(f"unknown event op {op!r}")
+
+    def _updated(self, resp, stats):
+        self.version += 1
+        edges = sum(s.n_inserted + s.n_deleted for s in stats)
+        self.updates.append(Update(edges, resp.ok))
 
     def _answer(self, q: Query, resp, now: float):
         q.answered = now
@@ -112,7 +159,7 @@ class Driver:
         q.passes = resp.value["passes"]
         q.path = ("refresh" if resp.value["refreshed"] else
                   "pruned" if resp.value["pruned"] else "warm")
-        if q.idx in self.sample:
+        if self.masks:
             m = self.svc.membership(q.tenant)
             q.mask = np.asarray(m.value["mask"], bool) if m.ok else None
 
@@ -154,11 +201,11 @@ def _start_profiler(trace_dir: str):
     jax.profiler.start_trace(trace_dir, profiler_options=opts)
 
 
-def _draw(rng, items, k: int) -> set:
+def _draw(rng, items: list, k: int) -> set:
     if not items:
         return set()
-    return set(rng.choice(items, size=min(k, len(items)),
-                          replace=False).tolist())
+    picks = rng.choice(len(items), size=min(k, len(items)), replace=False)
+    return {items[i] for i in picks.tolist()}
 
 
 def check_answers(gen, ref_mod, queries, sample: set):
@@ -167,7 +214,7 @@ def check_answers(gen, ref_mod, queries, sample: set):
     wrong = {"wrong_density": 0, "wrong_mask": 0, "wrong_passes": 0,
              "unanswered": 0}
     checked = 0
-    picked = sorted((q for q in queries if q.idx in sample),
+    picked = sorted((q for q in queries if q.key in sample),
                     key=lambda q: q.version)
     for q in picked:
         if not q.ok or q.answered is None:
@@ -221,10 +268,9 @@ def run_cell(bench_dir: str, manifest: dict, cell_name: str, seed: int,
     # the loop runs an unknown prefix of its events, so every answer keeps
     # its mask and the sample is drawn, from the seed, among those that ran
     rng = np.random.default_rng([seed, 1])
-    sample = {i for i, e in enumerate(gen.events) if e[0] == "density"}
 
     svc = gen.setup(make_service)
-    driver = Driver(svc, set())
+    driver = Driver(svc, masks=False)
     # warm-up: the mix's own traffic, so the window meets the shapes (batch
     # widths, prune buckets, refresh) compiled; every warm update must
     # land, since the generator's log assumes them
@@ -233,7 +279,8 @@ def run_cell(bench_dir: str, manifest: dict, cell_name: str, seed: int,
     bad = [r for r in driver.queries + driver.updates if not r.ok]
     if bad:
         raise RuntimeError(f"{len(bad)} warm-up requests failed")
-    driver.queries, driver.updates, driver.sample = [], [], sample
+    driver.queries, driver.updates, driver.masks = [], [], True
+    driver.groups = {op: [] for op in driver.groups}
 
     compiles = _compile_counter()
     trace_dir = None
@@ -288,7 +335,7 @@ def run_cell(bench_dir: str, manifest: dict, cell_name: str, seed: int,
     gc.collect()
     failed = (sum(not u.ok for u in driver.updates)
               + sum(not q.ok for q in driver.queries))
-    sample = _draw(rng, [q.idx for q in driver.queries], gen.check_sample)
+    sample = _draw(rng, [q.key for q in driver.queries], gen.check_sample)
     t_check = time.perf_counter()
     wrong, checked = check_answers(gen, ref_mod, driver.queries, sample)
     t_check = time.perf_counter() - t_check
@@ -304,6 +351,7 @@ def run_cell(bench_dir: str, manifest: dict, cell_name: str, seed: int,
         "bench paths " + json.dumps(_count(q.path for q in driver.queries)),
         f"bench compiles_in_window={len(in_window)} "
         + json.dumps(_count(in_window)),
+        "bench groups " + json.dumps(_groups(driver, sample)),
     ]
     result = {
         "correct": bool(correct),
@@ -321,6 +369,19 @@ def run_cell(bench_dir: str, manifest: dict, cell_name: str, seed: int,
         result["breakdown"] = xtrace.breakdown(summary)
     result["checks"] = {k: {"value": v, "limit": 0} for k, v in wrong.items()}
     return result, lines
+
+
+def _groups(driver: Driver, sample: set) -> dict:
+    """The window's grouped events of each op (how many, mean and largest
+    group), and how many checked answers came from a group of two or
+    more."""
+    out = {op: {"events": len(sizes),
+                "mean": sum(sizes) / len(sizes) if sizes else 0.0,
+                "max": max(sizes, default=0)}
+           for op, sizes in driver.groups.items()}
+    out["checked_in_groups"] = sum(
+        q.key in sample and q.ok and q.group >= 2 for q in driver.queries)
+    return out
 
 
 def _count(items) -> dict:

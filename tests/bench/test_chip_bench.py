@@ -1,9 +1,11 @@
 """Tests of the chip benchmark under ``bench/``, on the CPU at tiny sizes.
 
 They drive the harness the way ``bench/run.py`` does, minus the look for a
-TPU: a dummy cell added as data only, the control that must come out not
-correct, the faults a timed path can have, the trace reduction on a
-recorded trace, the generators' determinism, and the manifest's contract.
+TPU: tiny cells added as new files only (one of them many fused tenants
+behind the grouped ops, with a generator of its own), the control that
+must come out not correct, the faults a timed path can have, the trace
+reduction on a recorded trace, the generators' determinism, and the
+manifest's contract.
 """
 from __future__ import annotations
 
@@ -46,19 +48,38 @@ TINY = {
         {"generator": "sliding_window", "batch_edges": 64,
          "queries_every": 1, "max_batches": 4000, "warmup_batches": 4,
          "check_sample": 8}),
+    # fused tenants in four buckets (two hold two tenants each), queried
+    # in groups that only the harness's own flush answers; every answer
+    # of the window is checked
+    "tiny-tenants": (
+        {"name": "tiny-tenants", "reference": "peel", "chips": 1,
+         "tenant_scales": [6, 6, 7, 8, 8, 9], "edge_factor": 4,
+         "initiator": [0.57, 0.19, 0.19],
+         "service": {"fused": True, "pruned": True, "refresh_every": 8,
+                     "coalesce_window_ms": 3.6e6}},
+        {"generator": "tenant_windows", "batch_edges": 16, "group_min": 2,
+         "singles": 1, "max_rounds": 1000, "warmup_rounds": 2,
+         "check_sample": 10000}),
 }
+# a generator that ``bench/traffic/`` lacks, added to the copy as a file
+GENERATORS = {"tenant_windows": os.path.join(DATA, "tenant_windows.py")}
+# the cells whose spans the per-layer readers read
+LAYERED = ("tiny-panes", "tiny-queries")
 
 
 @pytest.fixture(scope="module")
 def tiny_bench(tmp_path_factory):
-    """A copy of ``bench/`` with the tiny cells added as data files only,
-    and a manifest that lists them beside the real cells."""
+    """A copy of ``bench/`` with the tiny cells added as new files only,
+    and a manifest that lists them beside the real cells; no file of the
+    copy is edited, and no end-to-end metric's entry either."""
     root = tmp_path_factory.mktemp("bench_copy")
     bench = os.path.join(root, "bench")
     shutil.copytree(BENCH, bench,
                     ignore=shutil.ignore_patterns("__pycache__"))
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         manifest = json.load(f)
+    for name, path in GENERATORS.items():
+        shutil.copy(path, os.path.join(bench, "traffic", name + ".py"))
     for name, (config, mix) in TINY.items():
         with open(os.path.join(bench, "configs", name + ".json"), "w") as f:
             json.dump(config, f)
@@ -67,8 +88,8 @@ def tiny_bench(tmp_path_factory):
         manifest["workloads"].append(
             {"name": name, "config": name, "traffic": name, "chips": 1,
              "why": "tiny CPU cell"})
-        for m in manifest["end_to_end"] + manifest["per_layer"]:
-            if "workloads" in m:
+        for m in manifest["per_layer"]:
+            if name in LAYERED:
                 m["workloads"].append(name)
     return bench, manifest
 
@@ -90,6 +111,37 @@ def test_dummy_cell_added_as_data_runs_correct(tiny_bench, cell):
     checked = int(re.search(r"checked=(\d+)", lines[1]).group(1))
     assert checked >= 1
     assert result["metrics"]["edges_per_s"]["value"] > 0
+    assert result["metrics"]["setup_s"]["value"] > 0
+
+
+def test_tenants_cell_drives_the_grouped_paths(tiny_bench):
+    """The many-tenant cell goes through ``ingest_many`` and
+    ``density_many``: its checked answers include grouped ones, and the
+    window's spans show fused scatters over two lanes or more and
+    flushes of two tickets or more."""
+    from repro.obs.trace import get_tracer
+
+    bench, manifest = tiny_bench
+    t_start = time.perf_counter()
+    result, lines = harness.run_cell(bench, manifest, "tiny-tenants",
+                                     2**35 + 11, 1.0, False, t_start)
+    assert result["correct"], (result["checks"], lines)
+    assert result["failed"] == 0 and result["attempted"] > 0
+    assert {"edges_per_s", "setup_s"} <= set(result["metrics"])
+    groups = json.loads(next(line for line in lines
+                             if line.startswith("bench groups "))[13:])
+    assert groups["ingest_many"]["events"] > 0
+    assert groups["density_many"]["max"] >= 2
+    assert groups["checked_in_groups"] >= 1
+    setup_s = float(re.search(r"setup_s=(\S+)", lines[0]).group(1))
+    window_s = float(re.search(r"window_s=(\S+)", lines[0]).group(1))
+    t0 = (t_start + setup_s) * 1e9
+    spans = [r for r in get_tracer().ring()
+             if t0 <= r.t_mono_ns <= t0 + window_s * 1e9]
+    assert any(r.name == "fused_ingest" and r.attrs.get("n_lanes", 0) >= 2
+               for r in spans)
+    assert any(r.name == "service" and r.labels.get("op") == "flush"
+               and r.attrs.get("n_flushed", 0) >= 2 for r in spans)
 
 
 @pytest.mark.parametrize("cell", sorted(TINY))
@@ -108,10 +160,14 @@ def _faulty(kind):
     from repro.stream import StreamService
 
     class Faulty(StreamService):
-        """StreamService with one fault in its timed path; armed once the
-        set-up has filled the state (at the first query)."""
+        """StreamService with one fault in its timed path. The faults of
+        the single-tenant ops are armed once the set-up has filled the
+        state (at the first query); those of the grouped ops once the
+        window has begun (at the first mask read, which the harness
+        makes only there)."""
 
         armed = False
+        in_window = False
 
         def apply_updates(self, tenant, insert=None, delete=None):
             if self.armed and kind == "state_unchanged":
@@ -131,12 +187,39 @@ def _faulty(kind):
                 resp.value["density"] = float(np.nextafter(d, np.inf))
             return resp
 
+        def membership(self, tenant, **kw):
+            self.in_window = True
+            return super().membership(tenant, **kw)
+
+        def ingest_many(self, updates):
+            if self.in_window and kind == "dropped_tenant_batch":
+                updates = dict(list(updates.items())[1:])
+            return super().ingest_many(updates)
+
+        def flush(self):
+            tickets = [ticket for ticket, _, _ in self._pending]
+            n = super().flush()
+            if self.in_window and len(tickets) >= 2:
+                a, b = tickets[:2]
+                if kind == "swapped_answer":
+                    self._results[a], self._results[b] = (
+                        self._results[b], self._results[a])
+                if kind == "unanswered_ticket":
+                    self._results.pop(a)
+            return n
+
     return Faulty
 
 
-@pytest.mark.parametrize("fault", ["state_unchanged", "half_batch",
-                                   "altered_answer"])
-@pytest.mark.parametrize("cell", sorted(TINY))
+SINGLE_FAULTS = ["state_unchanged", "half_batch", "altered_answer"]
+GROUPED_FAULTS = ["swapped_answer", "unanswered_ticket",
+                  "dropped_tenant_batch"]
+FAULT_CASES = ([(cell, f) for cell in sorted(TINY) for f in SINGLE_FAULTS]
+               + [("tiny-tenants", f) for f in GROUPED_FAULTS])
+
+
+@pytest.mark.parametrize("cell,fault", FAULT_CASES,
+                         ids=[f"{c}-{f}" for c, f in FAULT_CASES])
 def test_fault_in_timed_path_is_not_correct(tiny_bench, cell, fault):
     result, _ = run_tiny(tiny_bench, cell, make_service=_faulty(fault))
     assert not result["correct"], result["checks"]
@@ -329,7 +412,23 @@ def test_manifest_follows_the_contract():
         assert e["source"] in ("device_trace", "program_span",
                                "program_counter", "host_clock")
     for w in names:
-        reports = [e for e in m["end_to_end"]
-                   if w in e.get("workloads", names)]
-        assert len(reports) >= 2
+        assert len(_reporting(m, w)) >= 2
         assert any(w in e.get("workloads", names) for e in m["per_layer"])
+
+
+def _reporting(manifest: dict, cell: str) -> list:
+    """The end-to-end metrics that ``cell`` reports."""
+    names = [w["name"] for w in manifest["workloads"]]
+    return [e for e in manifest["end_to_end"]
+            if cell in e.get("workloads", names)]
+
+
+def test_an_added_cell_reports_two_end_to_end_metrics():
+    """A cell appended to the manifest, with no metric's entry touched,
+    reports two end-to-end metrics or more: a later deployment adds its
+    cell without editing what is there."""
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        m = json.load(f)
+    m["workloads"].append(dict(m["workloads"][0], name="added-cell",
+                               traffic="added-traffic"))
+    assert len(_reporting(m, "added-cell")) >= 2
