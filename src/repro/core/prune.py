@@ -344,6 +344,32 @@ def maybe_shrink_plan(
     return new
 
 
+def hold_group_buckets(
+    held: tuple[int, int, int, int] | None, shapes
+) -> tuple[int, int, int, int]:
+    """The plan-bucket shape a group of plans is peeled in together (one
+    batched bucket peel), given the shape ``held`` for the group so far
+    (None at first) and the ``PrunePlan.buckets`` of this group's members.
+
+    The shape grows at once to the elementwise max of the members' shapes,
+    and shrinks to that max only when it fits ``BUCKET_SHRINK_HYSTERESIS``x
+    smaller on either axis, as :func:`maybe_shrink_plan` shrinks one plan.
+    Members whose plans differ by less than that (tenants of one size differ
+    by a power of two) share one shape whichever of them a group holds, so
+    the executable follows the group size; a group that contracts for good
+    leaves the wide shape. A bucket peel is exact in any bucket its
+    subproblem fits, so the shape never changes an answer."""
+    need = tuple(int(x) for x in np.max(np.asarray(list(shapes)), axis=0))
+    if held is None:
+        return need
+    if any(n > h for n, h in zip(need, held)):
+        return tuple(max(n, h) for n, h in zip(need, held))
+    if (need[0] * BUCKET_SHRINK_HYSTERESIS > held[0]
+            and need[1] * BUCKET_SHRINK_HYSTERESIS > held[1]):
+        return tuple(held)
+    return need
+
+
 # ---------------------------------------------------------------------------
 # device side: bucket peel with a second-level compaction ladder
 # ---------------------------------------------------------------------------
@@ -1028,6 +1054,7 @@ __all__ = [
     "merge_pruned_peel",
     "build_plan",
     "maybe_shrink_plan",
+    "hold_group_buckets",
     "make_sharded_plan",
     "plan_for_graph",
     "compact_candidates",

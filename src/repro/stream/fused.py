@@ -30,8 +30,8 @@ parallelism targets. This module shares the *launch* too (ISSUE 4):
     invariant asserted per query in tests/test_tenants.py and
     benchmarks/bench_tenants.py.
   * :func:`query_group` answers many tenants with at most one batched warm
-    peel per bucket plus one batched bucket peel per pruned plan-bucket
-    shape (plans grouped by ``PrunePlan.buckets``); the service's
+    peel and one batched bucket peel per bucket, the latter in the plan
+    shape the bucket holds (``TenantBatch.peel_buckets``); the service's
     coalescing window and ``top_k_densest`` route through it.
 
 Cost model: a fused flush gathers only the *queried* lanes into a pow-2
@@ -71,10 +71,10 @@ from repro.core.distributed import (
 from repro.core.pbahmani import PeelState
 from repro.core.prune import (
     _batched_bucket_peel_jit, _make_sharded_batched_bucket_peel,
-    merge_pruned_peel, prepare_pruned_peel,
+    hold_group_buckets, merge_pruned_peel, prepare_pruned_peel,
 )
 from repro.obs.audit import AUDITOR
-from repro.obs.trace import get_tracer, span
+from repro.obs.trace import get_tracer, span, tally
 from repro.refine.certify import (
     better_fraction, dual_fraction, make_certificate, max_fraction,
 )
@@ -283,7 +283,18 @@ class TenantBatch:
                                       # COO+adjacency fusion (one program
                                       # per ingest, dense or sparse)
         self.n_group_peels = 0  # fused query flushes
+        self._peel_buckets: tuple | None = None  # see peel_buckets
         self._alloc(self.lanes)
+
+    def peel_buckets(self, shapes) -> tuple[int, int, int, int]:
+        """The plan-bucket shape of this bucket's batched pruned peel for
+        members whose ``PrunePlan.buckets`` are ``shapes``: the shape held
+        from earlier flushes, grown to fit them and shrunk with the plans'
+        own hysteresis (``core.prune.hold_group_buckets``). A flush peels
+        all its pruned members in this one shape, so the executable depends
+        on the pow-2 group size, not on which tenants were asked."""
+        self._peel_buckets = hold_group_buckets(self._peel_buckets, shapes)
+        return self._peel_buckets
 
     @property
     def n_shards(self) -> int:
@@ -403,6 +414,7 @@ class TenantBatch:
         mm[:k] = masks
         write = (_make_sharded_mask_rows_write(self.mesh) if self.sharded
                  else _mask_rows_write_jit)
+        tally("h2d_bytes", li.nbytes + mm.nbytes)
         self._prev_mask = write(
             self._prev_mask, jnp.asarray(li), jnp.asarray(mm))
 
@@ -427,8 +439,9 @@ class TenantBatch:
             du[lane, :k] = r_du
             dv[lane, :k] = r_dv
             w[lane, :k] = r_w
-        args = (jnp.asarray(slots), jnp.asarray(su), jnp.asarray(sv),
-                jnp.asarray(du), jnp.asarray(dv), jnp.asarray(w))
+        rows_h = (slots, su, sv, du, dv, w)
+        tally("h2d_bytes", sum(a.nbytes for a in rows_h))
+        args = tuple(jnp.asarray(a) for a in rows_h)
         if self.dense:
             # one fused program: COO scatter + histogram + adjacency scatter
             self._src, self._dst, self._deg, self._adj = (
@@ -456,6 +469,7 @@ class TenantBatch:
         li[:g] = lanes
         ne = np.full(gp, int(n_edges[0]), np.int32)
         ne[:g] = n_edges
+        tally("h2d_bytes", li.nbytes + ne.nbytes)
         gather = (_make_sharded_lane_gather(self.mesh) if self.sharded
                   else _lane_gather_jit)
         src_g, dst_g, deg_g, mask_g = gather(
@@ -475,6 +489,8 @@ class TenantBatch:
     def gather_deg_rows(self, lanes) -> jax.Array:
         """Degree rows for a pow-2 group of lanes (the pruned host prepare
         reads these per member)."""
+        lanes = np.asarray(lanes, np.int32)
+        tally("h2d_bytes", lanes.nbytes)
         gather = (_make_sharded_deg_rows_gather(self.mesh) if self.sharded
                   else _rows_gather_jit)
         return gather(self._deg, jnp.asarray(lanes))
@@ -683,15 +699,27 @@ def _flush(batch: TenantBatch, members, refine: bool = False,
     Observability: the flush is one span + one audit record attributed to
     the *bucket* (tenant ``bucket:VxE``) — its dispatch shapes are group
     properties (lane-stack width, pow-2 group sizes, plan-bucket shapes),
-    not any single member's. The per-member latency share carries the
-    flush's ``compiled`` flag into each engine's first-call/steady split."""
+    not any single member's. Its children ``fused_prepare`` (plan rebuilds,
+    degree-row pull, host prepare and merge of pruned members),
+    ``fused_bucket_peel`` (one batched bucket peel and its read-back),
+    ``fused_warm_peel`` (``peel_rows`` and its read-back) and
+    ``fused_mask_write`` name the phases; the flush span counts the
+    members by path (``n_warm``, ``n_pruned``, ``n_fallback``: pruned
+    members sent to the warm peel) and the passes: ``lane_passes`` sums
+    padded group lanes x passes over the batched peels, ``member_passes``
+    the passes their members' own answers report, so their ratio is the
+    share of lane-passes spent on converged or padding lanes. The
+    per-member latency share carries the flush's ``compiled`` flag into
+    each engine's first-call/steady split."""
     label = f"bucket:{batch.node_capacity}x{batch.edge_capacity}"
     with span("fused_flush", tenant=label, engine="fused") as sp:
         AUDITOR.sync()  # member refreshes/plan state ran under their own keys
-        out, refined, cached, audit_shape = _flush_body(
+        out, refined, cached, audit_shape, counts = _flush_body(
             batch, members, refine, target_gap, max_refine_rounds)
         compiled = AUDITOR.record(label, "fused_flush", audit_shape)
         sp.set("members", len(members)).set("compiled", compiled)
+        for key, value in counts.items():
+            sp.set(key, value)
         if refine:
             sp.set("path", "refined")
         share = sp.elapsed_ms / max(len(members), 1)
@@ -737,6 +765,9 @@ def _flush_body(batch: TenantBatch, members, refine: bool,
     warm: list = []
     dispatches: list = []
     mask_writes: list = []  # (lane, full-width mask) warm-seed updates
+    n_fallback = 0
+    lane_passes = 0  # padded group lanes x passes, over the batched peels
+    member_passes = 0  # the batched members' own passes
     # a member with a valid memoized peel (possible only on the refined
     # path — plain query_group short-circuits those before the flush)
     # reuses it as the refinement seed instead of re-peeling its lane
@@ -748,92 +779,104 @@ def _flush_body(batch: TenantBatch, members, refine: bool,
             out[name] = eng._cached_query
         else:
             live.append((name, eng))
-    for name, eng in live:
-        if eng.pruned and eng._plan is None:
-            eng._rebuild_plan()
-    # pull only the queried pruned lanes' degree rows, not the whole stack
-    pruned_lanes = [eng._lane for _, eng in live
-                    if eng.pruned and eng._plan.enabled]
-    deg_rows: dict[int, np.ndarray] = {}
-    if pruned_lanes:
-        gp = next_pow2(len(pruned_lanes))
-        li = np.full(gp, pruned_lanes[0], np.int32)
-        li[: len(pruned_lanes)] = pruned_lanes
-        rows = np.asarray(batch.gather_deg_rows(li))
-        deg_rows = {lane: rows[i] for i, lane in enumerate(pruned_lanes)}
-    for name, eng in live:
-        if eng.pruned:
-            if eng._plan.enabled:
-                u, v = eng.buffer.host_view()
-                prep = prepare_pruned_peel(
-                    u, v, deg_rows[eng._lane], eng.buffer.n_edges, eng.eps,
-                    eng._plan)
-                if prep is None:
-                    eng.metrics.n_prune_fallbacks += 1
-                    eng._plan = dc_replace(eng._plan, enabled=False)
-                    warm.append((name, eng))
-                elif isinstance(prep, tuple):
-                    density, mask, passes = eng._absorb_pruned_result(*prep)
-                    mask_writes.append(
-                        (eng._lane, np.asarray(eng._prev_mask)))
-                    out[name] = _pruned_result(density, mask, passes)
-                elif (batch.sharded
-                      and prep.plan.bucket_e % batch.n_shards):
+    with span("fused_prepare"):
+        for name, eng in live:
+            if eng.pruned and eng._plan is None:
+                eng._rebuild_plan()
+        # pull only the queried pruned lanes' degree rows, not the stack
+        pruned_lanes = [eng._lane for _, eng in live
+                        if eng.pruned and eng._plan.enabled]
+        deg_rows: dict[int, np.ndarray] = {}
+        if pruned_lanes:
+            gp = next_pow2(len(pruned_lanes))
+            li = np.full(gp, pruned_lanes[0], np.int32)
+            li[: len(pruned_lanes)] = pruned_lanes
+            rows = np.asarray(batch.gather_deg_rows(li))
+            tally("d2h_bytes", rows.nbytes)
+            deg_rows = {lane: rows[i] for i, lane in enumerate(pruned_lanes)}
+        for name, eng in live:
+            if not (eng.pruned and eng._plan.enabled):
+                warm.append((name, eng))
+                continue
+            u, v = eng.buffer.host_view()
+            prep = prepare_pruned_peel(
+                u, v, deg_rows[eng._lane], eng.buffer.n_edges, eng.eps,
+                eng._plan)
+            if prep is None or (
                     # mirror pruned_peel_host's mesh guard: bucket lanes
                     # that don't shard evenly re-peel unpruned instead
-                    eng.metrics.n_prune_fallbacks += 1
-                    eng._plan = dc_replace(eng._plan, enabled=False)
-                    warm.append((name, eng))
-                else:
-                    dispatches.append((name, eng, prep))
-            else:
+                    not isinstance(prep, tuple) and batch.sharded
+                    and prep.plan.bucket_e % batch.n_shards):
+                eng.metrics.n_prune_fallbacks += 1
+                eng._plan = dc_replace(eng._plan, enabled=False)
                 warm.append((name, eng))
-        else:
-            warm.append((name, eng))
+                n_fallback += 1
+            elif isinstance(prep, tuple):
+                density, mask, passes = eng._absorb_pruned_result(*prep)
+                mask_writes.append((eng._lane, np.asarray(eng._prev_mask)))
+                out[name] = _pruned_result(density, mask, passes)
+            else:
+                dispatches.append((name, eng, prep))
 
-    # plans grouped by bucket shape: one vmapped bucket peel per group
-    by_buckets = defaultdict(list)
-    for name, eng, pd in dispatches:
-        by_buckets[pd.plan.buckets].append((name, eng, pd))
-    for buckets, items in by_buckets.items():
-        bucket_v, bucket_e = buckets[0], buckets[1]
-        gp = next_pow2(len(items))
-        b_src = np.full((gp, bucket_e), bucket_v, np.int32)
-        b_dst = np.full((gp, bucket_e), bucket_v, np.int32)
-        n_v = np.zeros(gp, np.int32)
-        n_e = np.zeros(gp, np.int32)
-        best = np.zeros(gp, np.float32)
-        for i, (_, _, pd) in enumerate(items):
-            b_src[i], b_dst[i] = pd.b_src, pd.b_dst
-            n_v[i], n_e[i], best[i] = pd.n_v1, pd.n_e1, pd.best_d1
-        if batch.sharded:
-            d_b, mask_b, passes_b = _make_sharded_batched_bucket_peel(
-                batch.mesh, batch.eps, *buckets)(
-                    jnp.asarray(b_src), jnp.asarray(b_dst),
-                    jnp.asarray(n_v), jnp.asarray(n_e), jnp.asarray(best),
-                    jnp.ones(gp, jnp.int32))  # host simulated pass 0
-        else:
-            d_b, mask_b, passes_b = _batched_bucket_peel_jit(
-                jnp.asarray(b_src), jnp.asarray(b_dst), jnp.asarray(n_v),
-                jnp.asarray(n_e), jnp.asarray(best),
-                jnp.ones(gp, jnp.int32),  # host simulated pass 0 per lane
-                batch.eps, *buckets, batch.kernel)
-        d_b, mask_b = np.asarray(d_b), np.asarray(mask_b)
-        passes_b = np.asarray(passes_b)
-        for i, (name, eng, pd) in enumerate(items):
-            merged = merge_pruned_peel(pd, d_b[i], mask_b[i], passes_b[i])
-            density, mask, passes = eng._absorb_pruned_result(*merged)
-            mask_writes.append((eng._lane, np.asarray(eng._prev_mask)))
-            out[name] = _pruned_result(density, mask, passes)
+    # one vmapped bucket peel for every pruned member, in the bucket's
+    # held plan shape (TenantBatch.peel_buckets)
+    buckets = gp = None
+    if dispatches:
+        buckets = batch.peel_buckets(
+            [pd.plan.buckets for _, _, pd in dispatches])
+        with span("fused_bucket_peel"):
+            bucket_v, bucket_e = buckets[0], buckets[1]
+            gp = next_pow2(len(dispatches))
+            b_src = np.full((gp, bucket_e), bucket_v, np.int32)
+            b_dst = np.full((gp, bucket_e), bucket_v, np.int32)
+            n_v = np.zeros(gp, np.int32)
+            n_e = np.zeros(gp, np.int32)
+            best = np.zeros(gp, np.float32)
+            for i, (_, _, pd) in enumerate(dispatches):
+                # the member's sentinel (its own bucket_v) becomes the group's
+                k, own = pd.b_src.size, pd.plan.bucket_v
+                b_src[i, :k] = np.where(pd.b_src == own, bucket_v, pd.b_src)
+                b_dst[i, :k] = np.where(pd.b_dst == own, bucket_v, pd.b_dst)
+                n_v[i], n_e[i], best[i] = pd.n_v1, pd.n_e1, pd.best_d1
+            tally("h2d_bytes", sum(a.nbytes for a in
+                                   (b_src, b_dst, n_v, n_e, best)))
+            if batch.sharded:
+                d_b, mask_b, passes_b = _make_sharded_batched_bucket_peel(
+                    batch.mesh, batch.eps, *buckets)(
+                        jnp.asarray(b_src), jnp.asarray(b_dst),
+                        jnp.asarray(n_v), jnp.asarray(n_e),
+                        jnp.asarray(best),
+                        jnp.ones(gp, jnp.int32))  # host simulated pass 0
+            else:
+                d_b, mask_b, passes_b = _batched_bucket_peel_jit(
+                    jnp.asarray(b_src), jnp.asarray(b_dst), jnp.asarray(n_v),
+                    jnp.asarray(n_e), jnp.asarray(best),
+                    jnp.ones(gp, jnp.int32),  # host simulated pass 0
+                    batch.eps, *buckets, batch.kernel)
+            d_b, mask_b = np.asarray(d_b), np.asarray(mask_b)
+            passes_b = np.asarray(passes_b)
+            tally("d2h_bytes", d_b.nbytes + mask_b.nbytes + passes_b.nbytes)
+            lane_passes += gp * int(passes_b.max())
+            member_passes += int(passes_b[: len(dispatches)].sum())
+        with span("fused_prepare"):
+            for i, (name, eng, pd) in enumerate(dispatches):
+                merged = merge_pruned_peel(pd, d_b[i], mask_b[i], passes_b[i])
+                density, mask, passes = eng._absorb_pruned_result(*merged)
+                mask_writes.append((eng._lane, np.asarray(eng._prev_mask)))
+                out[name] = _pruned_result(density, mask, passes)
 
     if warm:
-        lanes = np.asarray([eng._lane for _, eng in warm], np.int32)
-        ne = np.asarray([eng.buffer.n_edges for _, eng in warm], np.int32)
-        final, warm_rho = batch.peel_rows(lanes, ne)
-        bd = np.asarray(final.best_density)
-        wr = np.asarray(warm_rho)
-        bm = np.asarray(final.best_mask)
-        ps = np.asarray(final.passes)
+        with span("fused_warm_peel"):
+            lanes = np.asarray([eng._lane for _, eng in warm], np.int32)
+            ne = np.asarray([eng.buffer.n_edges for _, eng in warm], np.int32)
+            final, warm_rho = batch.peel_rows(lanes, ne)
+            bd = np.asarray(final.best_density)
+            wr = np.asarray(warm_rho)
+            bm = np.asarray(final.best_mask)
+            ps = np.asarray(final.passes)
+            tally("d2h_bytes", bd.nbytes + wr.nbytes + bm.nbytes + ps.nbytes)
+            lane_passes += ps.size * int(ps.max())
+            member_passes += int(ps[: len(warm)].sum())
         for i, (name, eng) in enumerate(warm):
             density, wrho = float(bd[i]), float(wr[i])
             mask = bm[i][: eng.n_nodes].copy()
@@ -852,8 +895,9 @@ def _flush_body(batch: TenantBatch, members, refine: bool,
                 refreshed=False)
 
     if mask_writes:
-        batch.set_mask_rows([lane for lane, _ in mask_writes],
-                            np.stack([m for _, m in mask_writes]))
+        with span("fused_mask_write"):
+            batch.set_mask_rows([lane for lane, _ in mask_writes],
+                                np.stack([m for _, m in mask_writes]))
     batch.n_group_peels += 1
     refined = None
     if refine:
@@ -861,18 +905,23 @@ def _flush_body(batch: TenantBatch, members, refine: bool,
                                 max_refine_rounds)
     # every shape determinant of this flush's dispatches, for the audit key:
     # lane-stack width (gather inputs), pow-2 gather/peel/refine group
-    # sizes, and the plan-bucket shapes actually bucket-peeled
-    bucket_sig = tuple(sorted(
-        (bk, next_pow2(len(items))) for bk, items in by_buckets.items()))
+    # sizes, and the bucket peel's plan shape
     audit_shape = (
         batch.node_capacity, batch.edge_capacity, batch.eps, batch.lanes,
         batch.kernel, batch.n_shards,
         next_pow2(len(pruned_lanes)) if pruned_lanes else 0,
         next_pow2(len(warm)) if warm else 0,
-        bucket_sig,
+        buckets, gp,
         next_pow2(max(len(members), 1)) if refine else 0,
     )
-    return out, refined, cached, audit_shape
+    counts = {
+        "n_warm": len(warm),
+        "n_pruned": len(live) - len(warm),
+        "n_fallback": n_fallback,
+        "lane_passes": lane_passes,
+        "member_passes": member_passes,
+    }
+    return out, refined, cached, audit_shape, counts
 
 
 def _refine_flush(batch: TenantBatch, members, peel_out,
@@ -1088,6 +1137,7 @@ def ingest_group(updates: dict[str, tuple], engines: dict[str, DeltaEngine]):
                     (batch.node_capacity, batch.edge_capacity, batch.eps,
                      batch.lanes, batch.kernel, batch.n_shards, b))
                 sp.set("n_lanes", len(rows)).set("compiled", compiled)
+                sp.set("stack_lanes", batch.lanes).set("batch_width", b)
     return stats
 
 

@@ -174,13 +174,18 @@ class StreamService:
     def ingest_many(self, updates: dict) -> ServiceResponse:
         """Apply many tenants' batches; fused tenants in the same capacity
         bucket share one ``[T, B]`` scatter program per flush.
-        ``updates`` maps tenant -> (insert, delete)."""
+        ``updates`` maps tenant -> (insert, delete). The span counts the
+        tenants (``n_tenants``) and the edges applied (``n_edges``:
+        effective inserts plus deletes)."""
         with span("service", op="ingest_many", tenant="-") as sp:
             try:
                 engines = {t: self._engine(t) for t in updates}
                 stats = ingest_group(updates, engines)
             except (ValueError, KeyError) as e:
                 return self._respond("ingest_many", None, sp, error=str(e))
+            sp.set("n_tenants", len(stats))
+            sp.set("n_edges", sum(s.n_inserted + s.n_deleted
+                                  for s in stats.values()))
             return self._respond(
                 "ingest_many", None, sp, value=stats,
                 compiled=any(s.compiled for s in stats.values()))

@@ -14,8 +14,9 @@ from _hyp import given, settings, st
 
 from repro.core import exact_densest, pbahmani, pbahmani_np
 from repro.core.prune import (
-    MIN_BUCKET_E, MIN_BUCKET_V, _plan_jit, build_plan, compact_candidates,
-    pbahmani_pruned, plan_for_graph,
+    BUCKET_SHRINK_HYSTERESIS, MIN_BUCKET_E, MIN_BUCKET_V, _plan_jit,
+    build_plan, compact_candidates, hold_group_buckets, pbahmani_pruned,
+    plan_for_graph,
 )
 from repro.graphs.generators import erdos_renyi, planted_dense, small_named
 from repro.graphs.graph import Graph
@@ -259,6 +260,24 @@ def test_service_reports_pruned_flag():
     svc.create_tenant("legacy", n_nodes=64, pruned=False)
     assert not svc.registry.get("legacy").pruned
     assert not svc.stats("legacy").value.pruned
+
+
+H = BUCKET_SHRINK_HYSTERESIS
+
+
+@pytest.mark.parametrize("held,shapes,expect", [
+    (None, [(128, 512, 64, 256), (256, 256, 64, 256)], (256, 512, 64, 256)),
+    ((256, 512, 64, 256), [(512, 256, 64, 256)], (512, 512, 64, 256)),
+    ((256, 512, 64, 256), [(128, 256, 64, 256)], (256, 512, 64, 256)),
+    ((256, 512, 64, 256), [(256 // H, 512, 64, 256)], (256 // H, 512, 64, 256)),
+    ((256, 4096, 64, 512), [(128, 4096 // H, 64, 256)],
+     (128, 4096 // H, 64, 256)),
+], ids=["first", "grow", "hold", "shrink_v", "shrink_e"])
+def test_hold_group_buckets(held, shapes, expect):
+    """A group's held plan shape grows to its members' elementwise max at
+    once and shrinks to it only past the plans' own hysteresis, on either
+    axis (as ``maybe_shrink_plan``)."""
+    assert hold_group_buckets(held, shapes) == expect
 
 
 def test_engine_mid_epoch_bucket_shrink():

@@ -16,6 +16,7 @@ import pytest
 from _hyp import given, settings, st
 
 from repro.core.pbahmani import pbahmani_np
+from repro.core.prune import BUCKET_SHRINK_HYSTERESIS
 from repro.stream import (
     DeltaEngine, FusedEngine, FusedPool, GraphRegistry, StreamService,
     ingest_group, query_group,
@@ -362,6 +363,179 @@ def test_group_helpers_accept_unbatched_engines():
                           "f": (np.array([[5, 6]]), None)},
                          {"plain": plain, "f": fe})
     assert stats["plain"].n_inserted == 1 and stats["f"].n_inserted == 1
+
+
+# ---------------------------------------------------------------------------
+# fused-layer spans and transfer counters
+# ---------------------------------------------------------------------------
+def _clique_engines(pool, n, pruned_flags, rng):
+    """Fused engines of one bucket, each a clique of its own size in
+    random noise; ``pruned_flags`` picks each one's path."""
+    engines = {}
+    for i, pruned in enumerate(pruned_flags):
+        eng = FusedEngine(f"t{i}", pool, n, refresh_every=10**9,
+                          pruned=pruned)
+        k = 10 + i
+        clique = np.array([(a, b) for a in range(k) for b in range(a + 1, k)])
+        eng.apply_updates(insert=np.concatenate(
+            [clique, rng.integers(0, n, (400, 2))]))
+        engines[eng.name] = eng
+    return engines
+
+
+def test_fused_flush_phase_spans_and_pass_counts(monkeypatch):
+    """A flush of a mixed pruned and warm group opens its four phase
+    spans under ``fused_flush``, which counts the members by path;
+    ``member_passes`` is the sum of the answers' passes and
+    ``lane_passes`` the padded group lanes x passes of each batched
+    peel (counted here at the two batched entry points). The answers
+    stay bit-identical to solo engines fed the same edges."""
+    from repro.obs.trace import Tracer, set_tracer
+    from repro.stream import fused as fused_mod
+
+    peels = []  # (padded group lanes, passes) of each batched peel
+    real_bucket = fused_mod._batched_bucket_peel_jit
+    real_rows = fused_mod.TenantBatch.peel_rows
+
+    def bucket_peel(*a, **k):
+        out = real_bucket(*a, **k)
+        passes = np.asarray(out[2])
+        peels.append((passes.size, int(passes.max())))
+        return out
+
+    def peel_rows(self, lanes, n_edges):
+        final, rho = real_rows(self, lanes, n_edges)
+        passes = np.asarray(final.passes)
+        peels.append((passes.size, int(passes.max())))
+        return final, rho
+
+    monkeypatch.setattr(fused_mod, "_batched_bucket_peel_jit", bucket_peel)
+    monkeypatch.setattr(fused_mod.TenantBatch, "peel_rows", peel_rows)
+    tr = Tracer(profiler_bridge=False)
+    prev = set_tracer(tr)
+    try:
+        engines = _clique_engines(FusedPool(), 300,
+                                  [True, True, True, False, False],
+                                  np.random.default_rng(3))
+        results = query_group(engines)
+    finally:
+        set_tracer(prev)
+    ring = tr.ring()
+    flush = next(r for r in ring if r.name == "fused_flush")
+    children = {r.name for r in ring if r.parent_id == flush.span_id}
+    assert {"fused_prepare", "fused_bucket_peel", "fused_warm_peel",
+            "fused_mask_write"} <= children
+    assert flush.attrs["members"] == 5
+    assert flush.attrs["n_warm"] == 2 and flush.attrs["n_pruned"] == 3
+    assert flush.attrs["n_fallback"] == 0
+    assert sum(q.pruned for q in results.values()) == 3
+    assert flush.attrs["member_passes"] == sum(q.passes
+                                               for q in results.values())
+    assert len(peels) == 2
+    assert flush.attrs["lane_passes"] == sum(g * p for g, p in peels)
+    assert flush.attrs["lane_passes"] >= flush.attrs["member_passes"]
+    # the reads back and the uploads are counted on the phase spans
+    by_name = {r.name: r for r in ring if r.parent_id == flush.span_id}
+    assert by_name["fused_bucket_peel"].attrs["h2d_bytes"] > 0
+    assert by_name["fused_bucket_peel"].attrs["d2h_bytes"] > 0
+    assert by_name["fused_warm_peel"].attrs["d2h_bytes"] > 0
+    assert by_name["fused_mask_write"].attrs["h2d_bytes"] > 0
+    for name, eng in engines.items():
+        ref = DeltaEngine(n_nodes=300, refresh_every=10**9,
+                          pruned=eng.pruned)
+        ref.apply_updates(insert=eng.buffer.live_pairs())
+        q = ref.query()
+        assert q.density == results[name].density, name
+        assert q.passes == results[name].passes, name
+        assert np.array_equal(q.mask, results[name].mask), name
+
+
+def test_fused_flush_peels_in_the_high_water_shape(monkeypatch):
+    """A bucket's pruned members share one batched bucket peel in the
+    widest plan shape the bucket has met, so later flushes keep that
+    executable whoever they ask, until the members' plans fit
+    ``BUCKET_SHRINK_HYSTERESIS`` x smaller on an axis; peeled in a wider
+    bucket than their own plans, the triples stay bit-identical to solo
+    engines."""
+    from repro.stream import fused as fused_mod
+
+    shapes = []  # (padded group lanes, bucket_v, bucket_e, v2, e2)
+    srcs = []
+    real_bucket = fused_mod._batched_bucket_peel_jit
+
+    def bucket_peel(*a, **k):
+        shapes.append((a[0].shape[0],) + tuple(a[7:11]))
+        srcs.append(np.asarray(a[0]))
+        return real_bucket(*a, **k)
+
+    monkeypatch.setattr(fused_mod, "_batched_bucket_peel_jit", bucket_peel)
+    rng = np.random.default_rng(5)
+    engines = _clique_engines(FusedPool(), 300, [True] * 3, rng)
+    query_group(engines)
+    assert len(shapes) == 1
+    own = shapes[0][1:]
+    batch = next(iter(engines.values())).batch
+    assert batch.peel_buckets([own]) == own
+    wide = (2 * own[0], 2 * own[1], own[2], own[3])
+    assert batch.peel_buckets([wide, own]) == wide
+    for eng in engines.values():
+        eng.apply_updates(insert=rng.integers(0, 300, (40, 2)))
+    results = query_group(dict(list(engines.items())[:2]))
+    assert shapes[1:] == [(2,) + wide]
+    # the members' rows are padded with the group's sentinel, not their own
+    assert np.all((srcs[1] < own[0]) | (srcs[1] == wide[0]))
+    assert all(q.pruned for q in results.values())
+    for name, q in results.items():
+        ref = DeltaEngine(n_nodes=300, refresh_every=10**9, pruned=True)
+        ref.apply_updates(insert=engines[name].buffer.live_pairs())
+        r = ref.query()
+        assert (r.density, r.passes) == (q.density, q.passes), name
+        assert np.array_equal(r.mask, q.mask), name
+    # held while the plans are within the hysteresis, shrunk past it
+    assert batch.peel_buckets([own]) == wide
+    small = (wide[0] // BUCKET_SHRINK_HYSTERESIS,) + wide[1:]
+    assert batch.peel_buckets([small]) == small
+
+
+def test_fused_ingest_counts_its_rows():
+    """``fused_ingest`` carries the stack width and the batch width, and
+    counts as ``h2d_bytes`` exactly the ``[T, B]`` rows it uploads (six
+    int32 arrays); the bucket's ``h2d_bytes_total`` counter grows by the
+    same bytes. The service's ``ingest_many`` span counts the tenants and
+    the edges applied."""
+    from repro.obs.trace import Tracer, set_tracer
+
+    tr = Tracer(profiler_bridge=False)
+    prev = set_tracer(tr)
+    try:
+        svc = StreamService(fused=True)
+        for name in ("a", "b", "c"):
+            svc.create_tenant(name, n_nodes=90)
+            svc.apply_updates(name, insert=np.array([[0, 1], [1, 2]]))
+        label = "bucket:128x256"
+        counter = tr.registry.counter("h2d_bytes_total", tenant=label,
+                                      engine="fused")
+        before = counter.value
+        rng = np.random.default_rng(4)
+        resp = svc.ingest_many({
+            name: (rng.integers(0, 90, (70, 2)),
+                   np.array([[0, 1]]) if name == "a" else None)
+            for name in ("a", "b", "c")})
+    finally:
+        set_tracer(prev)
+    assert resp.ok
+    rec = next(r for r in tr.ring() if r.name == "fused_ingest")
+    assert rec.labels["tenant"] == label
+    lanes, b = rec.attrs["stack_lanes"], rec.attrs["batch_width"]
+    assert rec.attrs["n_lanes"] == 3 and lanes == MIN_LANES and b == 128
+    assert rec.attrs["h2d_bytes"] == 6 * lanes * b * 4
+    assert counter.value - before == rec.attrs["h2d_bytes"]
+    op = next(r for r in tr.ring() if r.name == "service"
+              and r.labels.get("op") == "ingest_many")
+    assert op.attrs["n_tenants"] == 3
+    assert op.attrs["n_edges"] == sum(
+        s.n_inserted + s.n_deleted for s in resp.value.values())
+    assert op.attrs["n_edges"] > 0
 
 
 # ---------------------------------------------------------------------------
