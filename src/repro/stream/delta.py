@@ -857,14 +857,21 @@ class DeltaEngine:
                     jnp.asarray(self.buffer.n_edges, jnp.int32),
                     self.node_capacity, self.kernel,
                 )
-            new = build_plan(
-                float(rho_lb), int(k), int(n_cand), int(ne_cand),
-                node_width=self.node_capacity,
-                lane_width=2 * self.buffer.capacity,
-                observed=self._last_handoff,
-                n_vertices=self.n_nodes,
-            )
+            new = self._install_plan(rho_lb, k, n_cand, ne_cand)
             sp.set("candidate_fraction", new.candidate_fraction)
+
+    def _install_plan(self, rho_lb, k, n_cand, ne_cand) -> PrunePlan:
+        """Host half of a plan rebuild: size the buckets from the plan
+        analysis' outputs (``_plan_jit``) and the last observed handoff,
+        install the plan and count it. Shared with the fused flush, which
+        reads many tenants' analyses back at once (stream/fused.py)."""
+        new = build_plan(
+            float(rho_lb), int(k), int(n_cand), int(ne_cand),
+            node_width=self.node_capacity,
+            lane_width=2 * self.buffer.capacity,
+            observed=self._last_handoff,
+            n_vertices=self.n_nodes,
+        )
         if self._plan is not None and new.buckets == self._plan.buckets:
             self.metrics.bucket_reuses += 1
         self._plan = new
@@ -872,6 +879,7 @@ class DeltaEngine:
         self.metrics.candidate_fraction = new.candidate_fraction
         self.metrics.prune_bucket_v = new.bucket_v
         self.metrics.prune_bucket_e = new.bucket_e
+        return new
 
     def _run_pruned_peel(self) -> tuple[float, np.ndarray, int] | None:
         """Host-compacted peel (prune.py): the device only ever touches the

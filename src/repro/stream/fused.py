@@ -31,8 +31,9 @@ parallelism targets. This module shares the *launch* too (ISSUE 4):
     benchmarks/bench_tenants.py.
   * :func:`query_group` answers many tenants with at most one batched warm
     peel and one batched bucket peel per bucket, the latter in the plan
-    shape the bucket holds (``TenantBatch.peel_buckets``); the service's
-    coalescing window and ``top_k_densest`` route through it.
+    shape the bucket holds (``TenantBatch.peel_buckets``); a stale tenant
+    takes its epoch refresh inside that flush, as one more member. The
+    service's coalescing window and ``top_k_densest`` route through it.
 
 Cost model: a fused flush gathers only the *queried* lanes into a pow-2
 group (``_lane_gather_jit``) before peeling, so one tenant's query costs
@@ -70,7 +71,7 @@ from repro.core.distributed import (
 )
 from repro.core.pbahmani import PeelState
 from repro.core.prune import (
-    _batched_bucket_peel_jit, _make_sharded_batched_bucket_peel,
+    _batched_bucket_peel_jit, _make_sharded_batched_bucket_peel, _plan_jit,
     hold_group_buckets, merge_pruned_peel, prepare_pruned_peel,
 )
 from repro.obs.audit import AUDITOR
@@ -142,6 +143,18 @@ def _rows_gather_jit(stack, lanes):
     dense peel, degree rows for the pruned host prepare) — flush cost stays
     proportional to the queried group, not the whole stack."""
     return stack[lanes]
+
+
+@partial(jax.jit, static_argnames=("n_nodes", "kernel"))
+def _lane_plan_jit(src, dst, mask, lane, n_edges, n_nodes: int, kernel: bool):
+    """The prune plan's analysis (``_plan_jit``: rho~ bootstrap and the
+    ceil(rho~)-core fixpoint) of one lane, read straight off the bucket's
+    stacks. The lane index is traced, so every member of a bucket shares
+    one executable and a plan build needs no row views; only the four
+    scalars ``build_plan`` sizes from come back."""
+    rho_lb, k, _, n_cand, ne_cand = _plan_jit(
+        src[lane], dst[lane], mask[lane], n_edges, n_nodes, kernel)
+    return rho_lb, k, n_cand, ne_cand
 
 
 @partial(jax.jit, static_argnames=("n_nodes",))
@@ -238,7 +251,7 @@ def _batched_dense_warm_peel_jit(adj, deg, n_edges, prev_mask, eps: float):
 
 
 FUSED_JITS = [_lane_write_jit, _mask_rows_write_jit, _lane_gather_jit,
-              _adj_lane_write_jit, _rows_gather_jit,
+              _adj_lane_write_jit, _rows_gather_jit, _lane_plan_jit,
               _batched_apply_dense_jit, _batched_dense_warm_peel_jit]
 
 
@@ -384,8 +397,13 @@ class TenantBatch:
 
     def write_lane(self, lane: int, src, dst, deg, mask,
                    generation: int) -> None:
+        """Write one tenant's host-built ``src``/``dst``/``deg`` rows (and,
+        in a dense bucket, its adjacency) into ``lane``; ``mask`` may be a
+        device row, which then stays on the device."""
         write = (_make_sharded_lane_write(self.mesh) if self.sharded
                  else _lane_write_jit)
+        tally("h2d_bytes", src.nbytes + dst.nbytes + deg.nbytes
+              + (self.node_capacity ** 2 * 4 if self.dense else 0))
         self._src, self._dst, self._deg, self._prev_mask = write(
             self._src, self._dst, self._deg, self._prev_mask,
             jnp.asarray(lane, jnp.int32), jnp.asarray(src, jnp.int32),
@@ -576,12 +594,12 @@ class FusedEngine(DeltaEngine):
     # -- device-state plumbing ---------------------------------------------
     def _sync_views(self) -> None:
         """Materialize this lane's rows as the ``_src``/``_dst``/``_deg``/
-        ``_prev_mask`` attributes the inherited host paths read (plan
-        rebuild, pruned prepare, cbds). Row slices share the unbatched
-        engines' executable shapes, so those paths stay cache hits; on a
-        sharded bucket the gather runs through ``_make_sharded_row_view``,
-        whose output shardings match ``_make_sharded_resync`` — the
-        inherited sharded entry points see the solo engine's placement."""
+        ``_prev_mask`` attributes the inherited host paths read (the sharded
+        plan rebuild, cbds). Row slices share the unbatched engines'
+        executable shapes, so those paths stay cache hits; on a sharded
+        bucket the gather runs through ``_make_sharded_row_view``, whose
+        output shardings match ``_make_sharded_resync`` — the inherited
+        sharded entry points see the solo engine's placement."""
         if self.sharded:
             batch = self.batch
             self._src, self._dst, self._deg, self._prev_mask = (
@@ -595,13 +613,35 @@ class FusedEngine(DeltaEngine):
         self._prev_mask = self.batch._prev_mask[self._lane]
 
     def _resync_device(self) -> None:
-        prev = np.asarray(self._prev_mask)
+        """Rewrite this lane from the buffer's slots. The warm-seed mask
+        row is rewritten from the engine's own device copy (kept equal to
+        the lane's row by every flush), never read back."""
         src, dst, deg = self.buffer.resident_state(self.node_capacity)
         self.pool.place(self)  # capacity changes migrate buckets here
-        self.batch.write_lane(self._lane, src, dst, deg, prev,
+        self.batch.write_lane(self._lane, src, dst, deg, self._prev_mask,
                               self.buffer.generation)
         self._generation = self.buffer.generation
-        self._sync_views()
+
+    def _reanchor_batch(self) -> "TenantBatch":
+        """The bucket this tenant's lane sits in once its epoch compaction
+        has run: ``epoch_compact(shrink=True)`` lands on the buffer's
+        ``shrink_target()`` when the hysteresis allows, else keeps the
+        capacity. A stale tenant joins that bucket's flush."""
+        cap = self.buffer.shrink_target() or self.buffer.capacity
+        return self.pool.batch_for(self.node_capacity, cap, self.eps,
+                                   self.kernel, mesh=self.mesh)
+
+    def _reanchor_lane(self) -> None:
+        """The epoch refresh's re-anchor, short of its peel, inside a fused
+        flush: compact the buffer (a shrink migrates the lane through
+        ``pool.place``), rewrite the lane from the compacted slots, and
+        restart the epoch. No read-back and no row views; the flush then
+        rebuilds the plan and peels this tenant with its other members."""
+        if self.buffer.epoch_compact(shrink=True):
+            self.metrics.n_buffer_shrinks += 1
+            self._plan = None  # lane-width sizing basis changed
+        self._resync_device()
+        self._staleness = 0.0
 
     def _dispatch_batch(self, slots, su, sv, du, dv, w) -> None:
         row = (slots, su, sv, du, dv, w)
@@ -620,48 +660,27 @@ class FusedEngine(DeltaEngine):
 
     # -- inherited paths that need fresh row views --------------------------
     def _rebuild_plan(self) -> None:
+        # a sharded bucket's per-member plan (make_sharded_plan); replicated
+        # buckets build their members' plans in the flush (_build_plans)
         self._sync_views()
         super()._rebuild_plan()
 
-    def _run_pruned_peel(self):
-        self._sync_views()
-        res = super()._run_pruned_peel()
-        if res is not None:
-            self._push_prev_mask()
-        return res
-
-    def _push_prev_mask(self) -> None:
-        self.batch.set_mask_rows([self._lane],
-                                 np.asarray(self._prev_mask)[None, :])
-
-    def _cold_full_peel(self):
-        """Epoch re-anchor through the batched peel (group of one). The
-        maintained-state init is bit-identical to ``init_state``'s cold
-        histogram, so the triple matches the unbatched ``_pbahmani_jit``."""
-        final, _ = self.batch.peel_rows(
-            np.asarray([self._lane], np.int32),
-            np.asarray([self.buffer.n_edges], np.int32))
-        row = jax.tree_util.tree_map(lambda x: x[0], final)
-        self.batch.set_mask_rows([self._lane],
-                                 np.asarray(row.best_mask)[None, :])
-        return row
-
     # -- queries ------------------------------------------------------------
+    def refresh(self) -> QueryResult:
+        """Epoch refresh as a flush of one: the lane re-anchors inside its
+        bucket's flush, the path a stale tenant takes in ``query_group``.
+        The triple equals the unbatched ``DeltaEngine.refresh``'s."""
+        return _flush(self._reanchor_batch(), [(self.name, self)],
+                      reanchor={self.name})[self.name]
+
     def query(self, refine: bool = False, target_gap: float | None = None,
               max_refine_rounds: int = 64) -> QueryResult:
-        if refine:
-            # group of one through the batched refinement flush: same
-            # executables as a full bucket sweep, compiled once per shape
-            return query_group(
-                {self.name: self}, refine=True, target_gap=target_gap,
-                max_refine_rounds=max_refine_rounds)[self.name]
-        if self._cached_query is not None:
-            return self._cached_query
-        if self._generation < 0:
-            self._resync_device()
-        if self.stale:
-            return self.refresh()
-        return query_group({self.name: self})[self.name]
+        # a group of one through the fused flush: same executables as a
+        # full bucket sweep, compiled once per shape; a stale tenant
+        # re-anchors there
+        return query_group(
+            {self.name: self}, refine=refine, target_gap=target_gap,
+            max_refine_rounds=max_refine_rounds)[self.name]
 
     def cbds(self, rounds: int = 1) -> dict:
         if self._generation < 0:
@@ -678,44 +697,74 @@ class FusedEngine(DeltaEngine):
 # ---------------------------------------------------------------------------
 # fused flushes
 # ---------------------------------------------------------------------------
-def _pruned_result(density: float, mask: np.ndarray,
-                   passes: int) -> QueryResult:
+def _pruned_result(density: float, mask: np.ndarray, passes: int,
+                   refreshed: bool) -> QueryResult:
     return QueryResult(density=density, mask=mask, passes=passes,
                        warm_density=density, warm_mask=mask.copy(),
-                       refreshed=False, pruned=True)
+                       refreshed=refreshed, pruned=True)
+
+
+def _build_plans(batch: TenantBatch, engines) -> None:
+    """Rebuild the prune plans of ``engines`` (members of ``batch``). A
+    replicated bucket enqueues one ``_lane_plan_jit`` per member, straight
+    off its stacks, and reads every member's four scalars back at once; a
+    sharded bucket keeps the per-member ``make_sharded_plan`` rebuild.
+    Each member's plan is the one its unbatched engine's rebuild gives."""
+    if batch.sharded:
+        for eng in engines:
+            eng._rebuild_plan()
+        return
+    pending = [_lane_plan_jit(
+        batch._src, batch._dst, batch._prev_mask,
+        jnp.asarray(eng._lane, jnp.int32),
+        jnp.asarray(eng.buffer.n_edges, jnp.int32),
+        batch.node_capacity, batch.kernel) for eng in engines]
+    for eng, out in zip(engines, jax.device_get(pending)):
+        eng._install_plan(*out)  # one-element scalars: no d2h tally
 
 
 def _flush(batch: TenantBatch, members, refine: bool = False,
            target_gap: float | None = None,
-           max_refine_rounds: int = 64) -> dict[str, QueryResult]:
-    """One fused flush for ``members`` (same bucket, warm path): at most one
-    batched bucket peel per plan-bucket shape plus one batched warm peel.
-    Per-tenant results are bit-identical to each engine's unbatched query
-    (same host prepare/merge, vmapped device recurrence). With ``refine``
-    the peel results seed one batched refinement-round loop for the whole
-    group (``_refine_flush``); the exact peel results still land in each
-    engine's plain query cache.
+           max_refine_rounds: int = 64,
+           reanchor=frozenset()) -> dict[str, QueryResult]:
+    """One fused flush for ``members`` (same bucket): at most one batched
+    bucket peel, in the plan shape the bucket holds, plus one batched warm
+    peel. Per-tenant results are bit-identical to each engine's unbatched
+    query (same host prepare/merge, vmapped device recurrence). With
+    ``refine`` the peel results seed one batched refinement-round loop for
+    the whole group (``_refine_flush``); the exact peel results still land
+    in each engine's plain query cache.
+
+    Members named in ``reanchor`` take their epoch refresh here, as members
+    of the flush: their lanes re-anchor (``FusedEngine._reanchor_lane``),
+    their plans are rebuilt with the others that need one, and from there
+    they are ordinary members; one that peels at full width takes the
+    refresh's semantics (its warm seed is its cold answer). Their answers
+    carry ``refreshed`` and count in ``n_refreshes``, the triple of the
+    unbatched ``DeltaEngine.refresh``.
 
     Observability: the flush is one span + one audit record attributed to
     the *bucket* (tenant ``bucket:VxE``) — its dispatch shapes are group
     properties (lane-stack width, pow-2 group sizes, plan-bucket shapes),
-    not any single member's. Its children ``fused_prepare`` (plan rebuilds,
-    degree-row pull, host prepare and merge of pruned members),
+    not any single member's. Its children ``fused_reanchor`` (compactions
+    and lane rewrites of the re-anchored members), ``fused_prepare`` (plan
+    rebuilds, degree-row pull, host prepare and merge of pruned members),
     ``fused_bucket_peel`` (one batched bucket peel and its read-back),
     ``fused_warm_peel`` (``peel_rows`` and its read-back) and
     ``fused_mask_write`` name the phases; the flush span counts the
-    members by path (``n_warm``, ``n_pruned``, ``n_fallback``: pruned
-    members sent to the warm peel) and the passes: ``lane_passes`` sums
-    padded group lanes x passes over the batched peels, ``member_passes``
-    the passes their members' own answers report, so their ratio is the
-    share of lane-passes spent on converged or padding lanes. The
-    per-member latency share carries the flush's ``compiled`` flag into
-    each engine's first-call/steady split."""
+    members by path (``n_reanchored``, ``n_warm``, ``n_pruned``,
+    ``n_fallback``: pruned members sent to the warm peel) and the passes:
+    ``lane_passes`` sums padded group lanes x passes over the batched
+    peels, ``member_passes`` the passes their members' own answers report,
+    so their ratio is the share of lane-passes spent on converged or
+    padding lanes. The per-member latency share carries the flush's
+    ``compiled`` flag into each engine's first-call/steady split."""
     label = f"bucket:{batch.node_capacity}x{batch.edge_capacity}"
+    reanchor = frozenset(reanchor)
     with span("fused_flush", tenant=label, engine="fused") as sp:
-        AUDITOR.sync()  # member refreshes/plan state ran under their own keys
+        AUDITOR.sync()  # member plan state ran under their own keys
         out, refined, cached, audit_shape, counts = _flush_body(
-            batch, members, refine, target_gap, max_refine_rounds)
+            batch, members, refine, target_gap, max_refine_rounds, reanchor)
         compiled = AUDITOR.record(label, "fused_flush", audit_shape)
         sp.set("members", len(members)).set("compiled", compiled)
         for key, value in counts.items():
@@ -735,6 +784,8 @@ def _flush(batch: TenantBatch, members, refine: bool = False,
             q = out[name]
             q.latency_ms = share
             q.compiled = compiled
+            if name in reanchor:
+                eng.metrics.n_refreshes += 1
             eng._note_query_ms(share, compiled)
             eng._cached_query = q
             if feed:
@@ -760,7 +811,8 @@ def _flush(batch: TenantBatch, members, refine: bool = False,
 
 
 def _flush_body(batch: TenantBatch, members, refine: bool,
-                target_gap: float | None, max_refine_rounds: int):
+                target_gap: float | None, max_refine_rounds: int,
+                reanchor: frozenset):
     out: dict[str, QueryResult] = {}
     warm: list = []
     dispatches: list = []
@@ -770,19 +822,27 @@ def _flush_body(batch: TenantBatch, members, refine: bool,
     member_passes = 0  # the batched members' own passes
     # a member with a valid memoized peel (possible only on the refined
     # path — plain query_group short-circuits those before the flush)
-    # reuses it as the refinement seed instead of re-peeling its lane
+    # reuses it as the refinement seed instead of re-peeling its lane; a
+    # re-anchored member never does
     cached: set[str] = set()
     live: list = []
     for name, eng in members:
-        if eng._cached_query is not None:
+        if eng._cached_query is not None and name not in reanchor:
             cached.add(name)
             out[name] = eng._cached_query
         else:
             live.append((name, eng))
+    reanchored = [(name, eng) for name, eng in live if name in reanchor]
+    if reanchored:
+        with span("fused_reanchor"):
+            for _, eng in reanchored:
+                eng._reanchor_lane()
+                assert eng.batch is batch, "re-anchor left the flush's bucket"
     with span("fused_prepare"):
-        for name, eng in live:
-            if eng.pruned and eng._plan is None:
-                eng._rebuild_plan()
+        planned = [eng for name, eng in live if eng.pruned
+                   and (name in reanchor or eng._plan is None)]
+        if planned:
+            _build_plans(batch, planned)
         # pull only the queried pruned lanes' degree rows, not the stack
         pruned_lanes = [eng._lane for _, eng in live
                         if eng.pruned and eng._plan.enabled]
@@ -813,8 +873,9 @@ def _flush_body(batch: TenantBatch, members, refine: bool,
                 n_fallback += 1
             elif isinstance(prep, tuple):
                 density, mask, passes = eng._absorb_pruned_result(*prep)
-                mask_writes.append((eng._lane, np.asarray(eng._prev_mask)))
-                out[name] = _pruned_result(density, mask, passes)
+                mask_writes.append((eng._lane, prep[1]))  # full width
+                out[name] = _pruned_result(density, mask, passes,
+                                           name in reanchor)
             else:
                 dispatches.append((name, eng, prep))
 
@@ -862,8 +923,9 @@ def _flush_body(batch: TenantBatch, members, refine: bool,
             for i, (name, eng, pd) in enumerate(dispatches):
                 merged = merge_pruned_peel(pd, d_b[i], mask_b[i], passes_b[i])
                 density, mask, passes = eng._absorb_pruned_result(*merged)
-                mask_writes.append((eng._lane, np.asarray(eng._prev_mask)))
-                out[name] = _pruned_result(density, mask, passes)
+                mask_writes.append((eng._lane, merged[1]))  # full width
+                out[name] = _pruned_result(density, mask, passes,
+                                           name in reanchor)
 
     if warm:
         with span("fused_warm_peel"):
@@ -880,7 +942,8 @@ def _flush_body(batch: TenantBatch, members, refine: bool,
         for i, (name, eng) in enumerate(warm):
             density, wrho = float(bd[i]), float(wr[i])
             mask = bm[i][: eng.n_nodes].copy()
-            if wrho > density:
+            # a re-anchor takes the refresh's cold answer as its warm seed
+            if wrho > density and name not in reanchor:
                 warm_density = wrho
                 warm_mask = np.asarray(eng._prev_mask)[: eng.n_nodes].copy()
                 # keep the stronger candidate as next query's warm seed
@@ -892,7 +955,7 @@ def _flush_body(batch: TenantBatch, members, refine: bool,
             out[name] = QueryResult(
                 density=density, mask=mask, passes=int(ps[i]),
                 warm_density=warm_density, warm_mask=warm_mask,
-                refreshed=False)
+                refreshed=name in reanchor)
 
     if mask_writes:
         with span("fused_mask_write"):
@@ -904,17 +967,20 @@ def _flush_body(batch: TenantBatch, members, refine: bool,
         refined = _refine_flush(batch, members, out, target_gap,
                                 max_refine_rounds)
     # every shape determinant of this flush's dispatches, for the audit key:
-    # lane-stack width (gather inputs), pow-2 gather/peel/refine group
-    # sizes, and the bucket peel's plan shape
+    # lane-stack width (gather, lane-write and plan inputs), whether lanes
+    # were rewritten and plans rebuilt (one program per member each, in
+    # the stack's shape), pow-2 gather/peel/refine group sizes, and the
+    # bucket peel's plan shape
     audit_shape = (
         batch.node_capacity, batch.edge_capacity, batch.eps, batch.lanes,
-        batch.kernel, batch.n_shards,
+        batch.kernel, batch.n_shards, bool(reanchored), bool(planned),
         next_pow2(len(pruned_lanes)) if pruned_lanes else 0,
         next_pow2(len(warm)) if warm else 0,
         buckets, gp,
         next_pow2(max(len(members), 1)) if refine else 0,
     )
     counts = {
+        "n_reanchored": len(reanchored),
         "n_warm": len(warm),
         "n_pruned": len(live) - len(warm),
         "n_fallback": n_fallback,
@@ -1044,12 +1110,13 @@ def query_group(engines: dict[str, DeltaEngine], refine: bool = False,
                 max_refine_rounds: int = 64) -> dict[str, QueryResult]:
     """Answer a set of tenants' densest-subgraph queries with fused
     execution wherever possible: fused tenants — replicated or sharded —
-    flush per-bucket (one batched warm peel + one batched bucket peel per
-    plan shape); non-fused engines fall back to their own query path; a
-    sharded bucket's flush issues one collective per pass for the whole
-    group. Cached results are
-    reused, and stale tenants take their epoch refresh individually first
-    (the refresh is epoch-amortized by design).
+    flush per bucket (one batched warm peel and one batched bucket peel,
+    see ``_flush``); non-fused engines fall back to their own query path;
+    a sharded bucket's flush issues one collective per pass for the whole
+    group. Cached results are reused. A stale tenant takes its epoch
+    refresh as a member of its bucket's flush (``_flush``'s ``reanchor``),
+    in the bucket its compaction lands it in, so the refresh shares the
+    flush's plan read-back, batched peels and mask write.
 
     ``refine=True`` answers with *certified* densities instead: fused
     members of a bucket share one batched refinement-round loop per flush
@@ -1059,6 +1126,7 @@ def query_group(engines: dict[str, DeltaEngine], refine: bool = False,
     certified-skip path of delta.py)."""
     out: dict[str, QueryResult] = {}
     flushes: dict[TenantBatch, list] = defaultdict(list)
+    reanchor: set[str] = set()
     tg = DEFAULT_TARGET_GAP if target_gap is None else float(target_gap)
     for name, eng in engines.items():
         if not isinstance(eng, FusedEngine):
@@ -1079,23 +1147,21 @@ def query_group(engines: dict[str, DeltaEngine], refine: bool = False,
             if skip is not None:
                 out[name] = skip
                 continue
-            if eng.stale:
-                eng.refresh()  # re-anchor; the refined flush runs below
-            flushes[eng.batch].append((name, eng))
-            continue
-        if eng._cached_query is not None:
+        elif eng._cached_query is not None:
             out[name] = eng._cached_query
+            continue
+        if eng.stale:
+            reanchor.add(name)
+            flushes[eng._reanchor_batch()].append((name, eng))
             continue
         if eng._generation < 0 or eng._generation != eng.buffer.generation:
             eng._resync_device()
-        if eng.stale:
-            out[name] = eng.refresh()
-            continue
         flushes[eng.batch].append((name, eng))
     for batch, members in flushes.items():
         out.update(_flush(batch, members, refine=refine,
                           target_gap=target_gap,
-                          max_refine_rounds=max_refine_rounds))
+                          max_refine_rounds=max_refine_rounds,
+                          reanchor=reanchor))
     return out
 
 

@@ -116,17 +116,22 @@ def test_fused_sharded_bucket_parity_one_device():
     whose batched programs run vmap-inside-shard_map — on the in-process
     1-device mesh every tenant stays bit-identical to its solo twin, and
     cbds routes through the same sharded tier (the multi-device version of
-    this oracle lives in tests/test_shard.py subprocesses)."""
+    this oracle lives in tests/test_shard.py subprocesses). Stale members
+    (``a`` every round, ``b`` every few) re-anchor inside the sharded
+    bucket's flush beside the fresh ``c``, with their per-member sharded
+    plan rebuilds."""
     rng = np.random.default_rng(9)
     n = 150
     reg = GraphRegistry(fused=True, sharded=True)
     names = ["a", "b", "c"]
+    every = {"a": 1, "b": 3, "c": 32}
     solo, edge_sets = {}, {}
     for t in names:
-        eng = reg.register(t, n_nodes=n)
+        eng = reg.register(t, n_nodes=n, refresh_every=every[t])
         assert eng.sharded and eng.kind == "fused+sharded"
-        solo[t] = DeltaEngine(n_nodes=n, refresh_every=32)
+        solo[t] = DeltaEngine(n_nodes=n, refresh_every=every[t])
         edge_sets[t] = set()
+    n_stale = 0
     for step in range(6):
         ups = {}
         for t in names:
@@ -134,12 +139,20 @@ def test_fused_sharded_bucket_parity_one_device():
             ups[t] = (ins, dels)
             solo[t].apply_updates(insert=ins, delete=dels)
         ingest_group(ups, reg.engines())
+        stale = {t for t in names if reg.get(t).stale}
+        assert "a" in stale and "c" not in stale, step
+        n_stale += len(stale)
         res = query_group(reg.engines())
         for t in names:
             qs = solo[t].query()
             assert res[t].density == qs.density, (step, t)
             assert res[t].passes == qs.passes, (step, t)
             assert np.array_equal(np.asarray(res[t].mask), qs.mask), (step, t)
+            assert res[t].refreshed == qs.refreshed == (t in stale), (step, t)
+    assert n_stale > 6  # b re-anchored too
+    for t in names:
+        assert (reg.get(t).metrics.n_refreshes
+                == solo[t].metrics.n_refreshes), t
     for t in names:
         cf, cs = reg.get(t).cbds(), solo[t].cbds()
         assert cf["density"] == cs["density"] and cf["n_legit"] == cs["n_legit"]
@@ -536,6 +549,212 @@ def test_fused_ingest_counts_its_rows():
     assert op.attrs["n_edges"] == sum(
         s.n_inserted + s.n_deleted for s in resp.value.values())
     assert op.attrs["n_edges"] > 0
+
+
+# ---------------------------------------------------------------------------
+# epoch refreshes inside the fused flush
+# ---------------------------------------------------------------------------
+def _assert_twin(q, r, label):
+    """(density, mask, passes, refreshed) of a fused answer ``q`` equal its
+    solo twin's ``r``."""
+    assert q.density == r.density, label
+    assert np.array_equal(np.asarray(q.mask), np.asarray(r.mask)), label
+    assert q.passes == r.passes, label
+    assert q.refreshed == r.refreshed, label
+
+
+def _pairs(rng, lo, hi, k):
+    e = rng.integers(lo, hi, (k, 2))
+    return e[e[:, 0] != e[:, 1]]
+
+
+# name: (n_nodes, refresh_every); "d_" tenants share a dense bucket
+# (V = 256), "s_" tenants a scatter bucket (V = 1024), all at 512 slots
+REANCHOR_SPEC = {
+    "d_stale": (150, 1), "d_mid": (150, 3), "d_fresh": (150, 10**9),
+    "d_clique": (150, 4),
+    "s_stale": (600, 1), "s_mid": (600, 3), "s_fresh": (600, 10**9),
+}
+CLIQUE = np.array([(a, b) for a in range(24) for b in range(a + 1, 24)])
+
+
+def _reanchor_round(rng, name, eng):
+    """One update batch for ``name``. ``d_clique`` keeps a 24-clique and
+    churns only leaf pairs: its pass-0 survivors (the clique) carry 552
+    lanes, more than the 512 of the largest legal bucket, so every plan of
+    it falls back to the full-width peel."""
+    n = REANCHOR_SPEC[name][0]
+    if eng.buffer.n_edges == 0:
+        if name == "d_clique":
+            return np.concatenate([CLIQUE, _pairs(rng, 24, n, 20)]), None
+        return _pairs(rng, 0, n, 330), None
+    live = eng.buffer.live_pairs()
+    if name == "d_clique":
+        leaves = live[live.min(axis=1) >= 24]
+        return (_pairs(rng, 24, n, 5),
+                leaves[rng.choice(len(leaves), 5, replace=False)])
+    return (_pairs(rng, 0, n, 20),
+            live[rng.choice(len(live), 10, replace=False)])
+
+
+def test_stale_members_reanchor_inside_the_flush_bit_identically():
+    """Epoch refreshes ride in their bucket's flush: over 8 rounds every
+    bucket's flush mixes stale tenants with fresh ones, in a dense bucket,
+    in a scatter bucket, and with a member whose plan falls back at every
+    re-anchor; every answer's (density, mask, passes, refreshed) equals its
+    solo twin's, the per-tenant refresh and plan counters agree, and after
+    the first round the auditor sees no steady-state recompile."""
+    from repro.obs.audit import AUDITOR
+
+    rng = np.random.default_rng(18)
+    pool = FusedPool()
+    fused, solo = {}, {}
+    for name, (n, every) in REANCHOR_SPEC.items():
+        fused[name] = FusedEngine(name, pool, n, refresh_every=every)
+        solo[name] = DeltaEngine(n_nodes=n, refresh_every=every)
+    steady = None
+    mixed = 0
+    for step in range(8):
+        ups = {name: _reanchor_round(rng, name, solo[name]) for name in fused}
+        for name, (ins, dels) in ups.items():
+            solo[name].apply_updates(insert=ins, delete=dels)
+        ingest_group(ups, fused)
+        stale = {name for name, eng in fused.items() if eng.stale}
+        for side in ("d_", "s_"):
+            group = {name for name in fused if name.startswith(side)}
+            mixed += bool(group & stale) and bool(group - stale)
+        res = query_group(fused)
+        for name, eng in fused.items():
+            _assert_twin(res[name], solo[name].query(), (step, name))
+            assert res[name].refreshed == (name in stale)
+            assert not eng.stale
+        if step == 0:
+            AUDITOR.sync()
+            steady = AUDITOR.n_steady_recompiles
+    assert mixed == 2 * 8  # both buckets, every round
+    assert fused["d_clique"].batch is fused["d_stale"].batch
+    assert fused["d_stale"].batch.dense
+    assert not fused["s_stale"].batch.dense
+    assert fused["d_clique"].metrics.n_prune_fallbacks >= 4
+    for name, eng in fused.items():
+        f, s = eng.metrics, solo[name].metrics
+        assert f.n_refreshes == s.n_refreshes, name
+        assert f.n_prune_fallbacks == s.n_prune_fallbacks, name
+        assert f.n_plan_builds == s.n_plan_builds, name
+        assert f.bucket_reuses == s.bucket_reuses, name
+    assert fused["d_stale"].metrics.n_refreshes == 8
+    assert fused["d_fresh"].metrics.n_refreshes == 0
+    AUDITOR.sync()
+    assert AUDITOR.n_steady_recompiles == steady, AUDITOR.snapshot(last=8)
+
+
+def _ring_children(ring, parent):
+    return [r for r in ring if r.parent_id == parent.span_id]
+
+
+def test_stale_member_reanchors_under_the_flush_span():
+    """A stale fused member opens no ``refresh`` span of its own: the
+    bucket's ``fused_flush`` counts it in ``n_reanchored``, and its
+    ``fused_reanchor`` child holds the member's buffer compaction."""
+    from repro.obs.trace import Tracer, set_tracer
+
+    rng = np.random.default_rng(7)
+    pool = FusedPool()
+    engines = {}
+    for name, every in (("a", 1), ("b", 1), ("c", 10**9)):
+        engines[name] = FusedEngine(name, pool, 300, refresh_every=every)
+        engines[name].apply_updates(insert=np.concatenate(
+            [CLIQUE[:100], _pairs(rng, 0, 300, 300)]))
+    query_group(engines)  # a and b are stale from their first batch
+    ingest_group({name: (_pairs(rng, 0, 300, 30), None)
+                  for name in engines}, engines)
+    assert {name for name, e in engines.items() if e.stale} == {"a", "b"}
+    tr = Tracer(profiler_bridge=False)
+    prev = set_tracer(tr)
+    try:
+        results = query_group(engines)
+    finally:
+        set_tracer(prev)
+    ring = tr.ring()
+    assert not [r for r in ring if r.name == "refresh"]
+    flush = next(r for r in ring if r.name == "fused_flush")
+    assert flush.attrs["members"] == 3 and flush.attrs["n_reanchored"] == 2
+    kids = _ring_children(ring, flush)
+    assert [k.name for k in kids][0] == "fused_reanchor"
+    compactions = _ring_children(ring, kids[0])
+    assert [c.name for c in compactions] == ["buffer_compact"] * 2
+    assert [results[n].refreshed for n in "abc"] == [True, True, False]
+    assert sum(e.metrics.n_refreshes for e in engines.values()) == 2 + 2
+    for name, eng in engines.items():
+        ref = DeltaEngine(n_nodes=300, refresh_every=10**9)
+        ref.apply_updates(insert=eng.buffer.live_pairs())
+        r = ref.query()
+        assert (r.density, r.passes) == (results[name].density,
+                                         results[name].passes), name
+        assert np.array_equal(r.mask, results[name].mask), name
+
+
+@pytest.mark.parametrize("pruned", [True, False])
+def test_lone_refresh_is_a_flush_of_one(pruned):
+    """``FusedEngine.refresh()`` re-anchors a fresh tenant as a flush of
+    one, whatever its cache holds: one ``fused_flush`` with one member,
+    re-anchored, no ``refresh`` span, and the solo engine's refresh triple
+    and counters."""
+    from repro.obs.trace import Tracer, set_tracer
+
+    rng = np.random.default_rng(21)
+    pool = FusedPool()
+    eng = FusedEngine("lone", pool, 150, refresh_every=10**9, pruned=pruned)
+    ref = DeltaEngine(n_nodes=150, refresh_every=10**9, pruned=pruned)
+    for ins in (np.concatenate([CLIQUE[:60], _pairs(rng, 0, 150, 200)]),
+                _pairs(rng, 0, 150, 40)):
+        eng.apply_updates(insert=ins)
+        ref.apply_updates(insert=ins)
+        _assert_twin(eng.query(), ref.query(), "query")
+    dels = ref.buffer.live_pairs()[:30]
+    eng.apply_updates(delete=dels)
+    ref.apply_updates(delete=dels)
+    _assert_twin(eng.query(), ref.query(), "query")
+    tr = Tracer(profiler_bridge=False)
+    prev = set_tracer(tr)
+    try:
+        q = eng.refresh()
+    finally:
+        set_tracer(prev)
+    r = ref.refresh()
+    _assert_twin(q, r, "refresh")
+    assert q.refreshed and q.pruned == r.pruned
+    assert eng.query() is q and eng._staleness == 0.0
+    assert eng.metrics.n_refreshes == ref.metrics.n_refreshes == 1
+    assert eng.metrics.n_queries == ref.metrics.n_queries
+    ring = tr.ring()
+    assert not [x for x in ring if x.name == "refresh"]
+    flush = [x for x in ring if x.name == "fused_flush"]
+    assert len(flush) == 1
+    assert flush[0].attrs["members"] == flush[0].attrs["n_reanchored"] == 1
+
+
+def test_reanchor_shrink_migrates_the_lane_into_the_flush_bucket():
+    """A re-anchor whose compaction shrinks the buffer moves the lane to
+    the smaller bucket (evict + join) and is answered by that bucket's
+    flush, with the solo engine's refresh triple."""
+    rng = np.random.default_rng(8)
+    pool = FusedPool()
+    eng = FusedEngine("s", pool, 200, refresh_every=3)
+    ref = DeltaEngine(n_nodes=200, refresh_every=3)
+    big = _pairs(rng, 0, 200, 3000)
+    eng.apply_updates(insert=big)
+    ref.apply_updates(insert=big)
+    _assert_twin(eng.query(), ref.query(), "grown")
+    wide = eng.batch
+    dels = ref.buffer.live_pairs()[100:]
+    eng.apply_updates(delete=dels)
+    ref.apply_updates(delete=dels)
+    assert eng.stale and eng._reanchor_batch() is not wide
+    _assert_twin(eng.query(), ref.query(), "shrunk")
+    assert eng.batch is not wide and "s" not in wide.lane_of
+    assert eng.batch.edge_capacity == eng.buffer.capacity < wide.edge_capacity
+    assert eng.metrics.n_buffer_shrinks == ref.metrics.n_buffer_shrinks == 1
 
 
 # ---------------------------------------------------------------------------
